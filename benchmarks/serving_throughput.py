@@ -224,7 +224,8 @@ def run_benchmark(*, arch_name: str, width: int, depth: int, vocab: int,
 
     from repro import configs
     from repro.core.sharding import use_mesh
-    from repro.launch.serve import resolve_serve_plan, serve_mesh
+    from repro.launch.mesh import host_mesh
+    from repro.launch.serve import resolve_serve_plan
     from repro.launch.train import reduced_arch
     from repro.models import model_module
     from repro.serve import ServeConfig, ServeEngine, blocks_for_request
@@ -236,7 +237,7 @@ def run_benchmark(*, arch_name: str, width: int, depth: int, vocab: int,
     # default) — the plan prices decode as that mixed step
     chunk = min(2 * kv_block_size if kv_block_size else 256, max_len)
     n_dev = jax.device_count()
-    mesh, mesh_spec = serve_mesh(n_dev)
+    mesh, mesh_spec = host_mesh(n_dev)
     plan = resolve_serve_plan(
         arch, mesh_spec if n_dev > 1 else None, plan_path=plan_path,
         strategy=strategy, prompt_len=max(prompt_buckets),

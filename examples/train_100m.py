@@ -18,8 +18,8 @@ if __name__ == "__main__":
         "--width", "640", "--depth", "8", "--vocab", "8192",
         "--batch", "8", "--seq", "256",
         "--steps", "300", "--lr", "1e-3",
-        "--ckpt-dir", "/tmp/repro_100m_ckpt", "--ckpt-every", "100",
-        "--metrics-out", "/tmp/repro_100m_metrics.json",
+        "--ckpt-dir", "results/100m_ckpt", "--ckpt-every", "100",
+        "--metrics-out", "results/100m_metrics.json",
     ] + sys.argv[1:]
     sys.argv = [sys.argv[0]] + argv
     train.main()

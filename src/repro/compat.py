@@ -1,37 +1,20 @@
-"""JAX version-compat layer: every version-sensitive symbol lives HERE.
+"""The few JAX symbols the tree spells in one place.
 
-The repo targets the paper's algorithms, not one JAX release; upstream has
-renamed or moved several symbols across 0.4.x -> 0.5.x -> 0.6.x:
-
-* ``pltpu.TPUCompilerParams`` became ``pltpu.CompilerParams``;
-* ``jax.sharding.AxisType`` (and the ``axis_types=`` kwarg of
-  ``jax.make_mesh``) only exist on newer releases;
-* explicit-sharding mode is absent on 0.4.x.
-
-No module outside this one may reference a versioned name — kernels and
-launchers import the stable aliases below, so a future rename is a one-line
-fix here instead of a tree-wide breakage.  Everything is feature-detected
-(``hasattr``/signature inspection), never version-string compared, so
-backports and nightlies resolve correctly too.
+The repo targets the installed JAX (0.9.0).  These helpers exist so that
+a future upstream rename is a one-line fix here instead of a tree-wide
+one: no module outside this one references ``CompilerParams`` or
+``AxisType`` (the CI lint job greps for it).
 """
 
 from __future__ import annotations
 
 import functools
-import inspect
 from typing import Any, Sequence
 
 import jax
-
-try:  # pallas is an optional extra on some CPU-only installs
-    from jax.experimental.pallas import tpu as _pltpu
-except ImportError:  # pragma: no cover - pallas always ships in our image
-    _pltpu = None
+from jax.experimental.pallas import tpu as _pltpu
 
 __all__ = [
-    "HAS_PALLAS",
-    "HAS_MESH_AXIS_TYPES",
-    "jax_version",
     "tpu_compiler_params",
     "make_mesh",
     "default_platform",
@@ -39,104 +22,37 @@ __all__ = [
     "shard_map",
 ]
 
-HAS_PALLAS = _pltpu is not None
-
-
-def jax_version() -> tuple[int, ...]:
-    """Installed JAX version as an int tuple (informational only —
-    feature gates below detect capabilities directly)."""
-    return tuple(int(p) for p in jax.__version__.split(".")[:3]
-                 if p.isdigit())
-
-
-# --------------------------------------------------------------------------- #
-# Pallas TPU compiler params: class was renamed across releases.
-# --------------------------------------------------------------------------- #
-_TPU_PARAMS_CLS = None
-if _pltpu is not None:
-    for _name in ("CompilerParams", "TPUCompilerParams"):
-        _TPU_PARAMS_CLS = getattr(_pltpu, _name, None)
-        if _TPU_PARAMS_CLS is not None:
-            break
-
 
 def tpu_compiler_params(*, dimension_semantics: Sequence[str], **kw) -> Any:
-    """Build the Mosaic compiler-params object under whichever name the
-    installed JAX exports; kwargs the class does not know are dropped."""
-    if _TPU_PARAMS_CLS is None:
-        raise RuntimeError("Pallas TPU backend is unavailable in this JAX")
-    fields = inspect.signature(_TPU_PARAMS_CLS).parameters
-    kw = {k: v for k, v in kw.items() if k in fields}
-    return _TPU_PARAMS_CLS(dimension_semantics=tuple(dimension_semantics),
-                           **kw)
-
-
-# --------------------------------------------------------------------------- #
-# Mesh construction: ``axis_types=`` / ``jax.sharding.AxisType`` are new.
-# --------------------------------------------------------------------------- #
-_AXIS_TYPE_CLS = getattr(jax.sharding, "AxisType", None)
-HAS_MESH_AXIS_TYPES = (
-    _AXIS_TYPE_CLS is not None
-    and "axis_types" in inspect.signature(jax.make_mesh).parameters)
+    """The Mosaic compiler-params object for a ``pallas_call``."""
+    return _pltpu.CompilerParams(
+        dimension_semantics=tuple(dimension_semantics), **kw)
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
-              axis_types: str | None = "auto", devices=None):
-    """``jax.make_mesh`` that only passes ``axis_types`` when the installed
-    JAX supports it.  ``axis_types`` is a *name* ("auto"/"explicit"/None),
-    resolved to the enum here so callers never touch ``AxisType``."""
-    kw: dict[str, Any] = {}
-    if devices is not None:
-        kw["devices"] = devices
-    if axis_types is not None and HAS_MESH_AXIS_TYPES:
-        enum = getattr(_AXIS_TYPE_CLS, axis_types.capitalize())
-        kw["axis_types"] = (enum,) * len(tuple(axis_names))
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kw)
+              devices=None):
+    """``jax.make_mesh`` with every axis in auto (GSPMD) sharding mode —
+    the repo shards through ``with_sharding_constraint``, not explicit
+    sharding types."""
+    auto = (jax.sharding.AxisType.Auto,) * len(tuple(axis_names))
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=auto, devices=devices)
 
 
-# --------------------------------------------------------------------------- #
-# shard_map: graduated from jax.experimental.shard_map to jax.shard_map.
-# --------------------------------------------------------------------------- #
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
+def shard_map(f, *, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with the replication check off (the profiling
+    microbench maps raw collectives whose replication XLA cannot always
+    infer)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_rep: bool = False):
-    """``shard_map`` under whichever home the installed JAX exports, with
-    the replication check disabled when the installed signature has it
-    (the profiling microbench maps raw collectives whose replication
-    XLA cannot always infer)."""
-    kw: dict[str, Any] = {}
-    params = inspect.signature(_shard_map).parameters
-    for name in ("check_rep", "check_vma"):  # renamed across releases
-        if name in params:
-            kw[name] = check_rep
-            break
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
-
-
-# --------------------------------------------------------------------------- #
-# Platform helpers
-# --------------------------------------------------------------------------- #
 @functools.lru_cache(maxsize=None)
 def default_platform() -> str:
     """'cpu' | 'gpu' | 'tpu' for the default JAX backend."""
     return jax.default_backend()
 
 
-# ``jax.core.Tracer`` has been shuffled across modules over releases.
-_TRACER_CLS = getattr(getattr(jax, "core", None), "Tracer", None)
-if _TRACER_CLS is None:  # pragma: no cover - future JAX layouts
-    _TRACER_CLS = getattr(getattr(jax, "extend", None), "core", None)
-    _TRACER_CLS = getattr(_TRACER_CLS, "Tracer", None)
-
-
 def is_tracer(x: Any) -> bool:
-    """True when ``x`` is an abstract tracer (inside jit/grad tracing).
-    Unknown class layout degrades to True — callers use this to skip
-    work that needs concrete values, so the safe answer is 'abstract'."""
-    if _TRACER_CLS is None:
-        return True
-    return isinstance(x, _TRACER_CLS)
+    """True when ``x`` is an abstract tracer (inside jit/grad tracing)."""
+    return isinstance(x, jax.core.Tracer)

@@ -90,6 +90,33 @@ TPU_V5E = ChipSpec(
 )
 
 
+#: Chips the cost model can price, keyed by ``jax.Device.device_kind``.
+#: Peaks are the published ones (Google Cloud documentation, "TPU v5e").
+CHIPS_BY_KIND: dict[str, ChipSpec] = {
+    "TPU v5 lite": TPU_V5E,
+}
+
+
+def chip_for(platform: str, device_kind: str, log=print) -> ChipSpec:
+    """The :class:`ChipSpec` the plan search prices for this device.
+
+    A TPU whose kind is not in :data:`CHIPS_BY_KIND` raises: pricing it
+    as some other chip would search plans for the wrong hardware.  A
+    host without a TPU (CPU tests, rehearsals) prices for a v5e and says
+    so through ``log``."""
+    if platform != "tpu":
+        log(f"cost model: no TPU here ({platform}); plans are priced "
+            f"for a {TPU_V5E.name}")
+        return TPU_V5E
+    try:
+        return CHIPS_BY_KIND[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"unknown TPU device_kind {device_kind!r}: the cost model has "
+            f"peaks for {sorted(CHIPS_BY_KIND)} only; add the chip's "
+            f"published peaks to CHIPS_BY_KIND") from None
+
+
 @dataclass(frozen=True)
 class CollectiveCost:
     """Cost of one collective: wall seconds and per-chip bytes sent."""

@@ -18,7 +18,14 @@ Overrides, strongest first:
   3. ``REPRO_KERNEL_BACKEND_<OP>`` env var (op name upper-cased);
   4. ``REPRO_KERNEL_BACKEND`` env var;
   5. automatic selection (highest-priority impl whose platform matches and
-     whose ``supports`` predicate accepts the arguments).
+     whose ``supports`` predicate accepts the arguments).  ``interpret``
+     (the Pallas interpreter) is a test harness: it runs only when asked
+     for by name, never as an automatic choice.
+
+Every resolution is counted per (op, backend) — :func:`selection_counts`
+— and every spmd-unsafe impl passed over under a mesh is counted in
+:func:`spmd_skips`, so a run can show which kernels it actually executed.
+Selection happens at trace time: the counts are traces, not calls.
 
 Ops registered by the sibling modules (canonical layouts/signatures):
   flash_attention(q, k, v, *, causal, block_q, block_k)
@@ -136,11 +143,10 @@ def _ensure_builtins() -> None:
     from . import mha_xla  # noqa: F401  chunked-XLA attention backend
     from . import mamba_scan  # noqa: F401  selective-scan backends
     from . import moe_kernels  # noqa: F401  MoE dispatch/combine backends
-    if compat.HAS_PALLAS:
-        from . import decode_attention  # noqa: F401
-        from . import flash_attention  # noqa: F401
-        from . import paged_decode_attention  # noqa: F401
-        from . import rwkv6_scan  # noqa: F401
+    from . import decode_attention  # noqa: F401
+    from . import flash_attention  # noqa: F401
+    from . import paged_decode_attention  # noqa: F401
+    from . import rwkv6_scan  # noqa: F401
 
 
 def backends(op: str) -> dict[str, Impl]:
@@ -212,18 +218,51 @@ def select(op: str, *args, backend: str | None = None, **kwargs) -> Impl:
     ranked = sorted(impls.values(), key=lambda i: -i.priority)
     spmd = _mesh_active()
     for impl in ranked:
-        if spmd and not impl.spmd_safe:
+        if impl.backend == "interpret":
             continue
-        if impl.eligible(platform, args, kwargs):
-            return impl
+        if not impl.eligible(platform, args, kwargs):
+            continue
+        if spmd and not impl.spmd_safe:
+            with _COUNT_LOCK:
+                _SPMD_SKIPS[(op, impl.backend)] += 1
+            continue
+        return impl
     raise RuntimeError(
         f"no eligible backend for op {op!r} on platform {platform!r}; "
         f"registered: {sorted(impls)}")
 
 
+_COUNT_LOCK = threading.Lock()
+_SELECTIONS: Counter = Counter()       # (op, backend) -> resolutions
+_SPMD_SKIPS: Counter = Counter()       # (op, backend) -> passed over
+
+
 def call(op: str, *args, backend: str | None = None, **kwargs):
     """Dispatch ``op`` to the selected backend implementation."""
-    return select(op, *args, backend=backend, **kwargs).fn(*args, **kwargs)
+    impl = select(op, *args, backend=backend, **kwargs)
+    with _COUNT_LOCK:
+        _SELECTIONS[(op, impl.backend)] += 1
+    return impl.fn(*args, **kwargs)
+
+
+def selection_counts() -> dict[tuple[str, str], int]:
+    """How often :func:`call` resolved each (op, backend) since the last
+    :func:`reset_selection_counts` (once per trace under ``jit``)."""
+    with _COUNT_LOCK:
+        return dict(_SELECTIONS)
+
+
+def spmd_skips() -> dict[tuple[str, str], int]:
+    """(op, backend) pairs that were eligible but passed over because a
+    multi-device mesh was active and the impl is not ``spmd_safe``."""
+    with _COUNT_LOCK:
+        return dict(_SPMD_SKIPS)
+
+
+def reset_selection_counts() -> None:
+    with _COUNT_LOCK:
+        _SELECTIONS.clear()
+        _SPMD_SKIPS.clear()
 
 
 # --------------------------------------------------------------------------- #
@@ -272,7 +311,8 @@ def autotune_cache_stats() -> dict[str, int]:
     """Counters: ``memory_hits`` / ``disk_hits`` (cache served), ``tuned``
     (a choice was computed fresh — heuristic or timed), ``disk_writes``,
     ``disk_errors`` (unreadable/corrupt cache files, recovered by
-    re-tuning)."""
+    re-tuning), ``compile_failures`` (timed candidates that failed to
+    compile or run, each logged at warning)."""
     with _TUNE_LOCK:
         return dict(_TUNE_STATS)
 
@@ -399,8 +439,10 @@ def tuned_blocks(op: str, key: tuple, candidates: list[tuple],
                 _time_once(bench, *cand)          # compile + warm up
                 timings.append((_time_once(bench, *cand), cand))
             except Exception:                     # candidate may not compile
-                log.debug("autotune candidate %s failed for %s",
-                          cand, op, exc_info=True)
+                with _TUNE_LOCK:
+                    _TUNE_STATS["compile_failures"] += 1
+                log.warning("autotune candidate %s failed for %s %s",
+                            cand, op, key, exc_info=True)
         if timings:
             choice = min(timings)[1]
             log.info("autotuned %s %s -> %s", op, key, choice)

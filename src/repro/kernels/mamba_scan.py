@@ -35,15 +35,13 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro import compat
 from repro.core.scan import remat_time_scan
 
 from . import dispatch
-
-if compat.HAS_PALLAS:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
 
 def _step(A, Dskip):
@@ -133,28 +131,42 @@ dispatch.register("mamba_scan", "xla", priority=50)(mamba_scan_xla)
 
 
 # --------------------------------------------------------------------------- #
-# Pallas kernel: state (N, di) f32 in VMEM scratch — di on the lane axis
-# (the wide dim, multiples of 128), N on the sublane axis.
+# Pallas kernel: state (N, block_d) f32 in VMEM scratch — channels on the
+# lane axis (multiples of 128), N on the sublane axis.  The recurrence is
+# independent per channel, so the grid tiles d_inner: a published width
+# (16384 for jamba) never has to fit VMEM whole.
 # --------------------------------------------------------------------------- #
+_PREF_D = (512, 256, 128)
+
+
 def _mamba_kernel(dt_ref, b_ref, c_ref, x_ref, at_ref, d_ref, o_ref, h_ref,
                   *, chunk: int):
-    ci = pl.program_id(1)
+    ci = pl.program_id(2)
 
     @pl.when(ci == 0)
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    At = at_ref[...].astype(jnp.float32)                  # (N, di)
-    Dv = d_ref[0].astype(jnp.float32)                     # (di,)
+    N = h_ref.shape[0]
+    At = at_ref[...].astype(jnp.float32)                  # (N, bd)
+    Dv = d_ref[...].astype(jnp.float32)                   # (1, bd)
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (N, N), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (N, N), 1))
+
+    def col(row):
+        # (1, N) -> (N, 1) as a masked lane reduction: Mosaic has no
+        # relayout from a lane vector to a sublane column
+        return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
 
     def step(t, h):
-        dt = dt_ref[0, t].astype(jnp.float32)             # (di,)
-        bt = b_ref[0, t].astype(jnp.float32)              # (N,)
-        ct = c_ref[0, t].astype(jnp.float32)              # (N,)
-        xt = x_ref[0, t].astype(jnp.float32)              # (di,)
-        h = jnp.exp(At * dt[None, :]) * h + bt[:, None] * (dt * xt)[None, :]
-        y = jnp.sum(h * ct[:, None], axis=0) + Dv * xt
-        o_ref[0, t] = y.astype(o_ref.dtype)
+        def row(ref):
+            return ref[0, pl.ds(t, 1), :].astype(jnp.float32)
+
+        dt, xt = row(dt_ref), row(x_ref)                  # (1, bd)
+        bt, ct = col(row(b_ref)), col(row(c_ref))         # (N, 1)
+        h = jnp.exp(At * dt) * h + bt * (dt * xt)
+        y = jnp.sum(h * ct, axis=0, keepdims=True) + Dv * xt
+        o_ref[0, pl.ds(t, 1), :] = y.astype(o_ref.dtype)
         return h
 
     h_ref[...] = jax.lax.fori_loop(0, chunk, step, h_ref[...])
@@ -167,25 +179,24 @@ def mamba_scan_pallas(dt, Bm, Cm, x, A, Dskip, *, chunk: int = 64,
     N = Bm.shape[-1]
     chunk = min(chunk, S)
     assert S % chunk == 0, (S, chunk)
-    n_chunks = S // chunk
-    grid = (B, n_chunks)
+    bd = dispatch.block_candidates(di, _PREF_D)[0]
+    grid = (B, di // bd, S // chunk)
 
     kernel = functools.partial(_mamba_kernel, chunk=chunk)
-
-    def seq(width):
-        return pl.BlockSpec((1, chunk, width), lambda b, ci: (b, ci, 0))
+    chan = pl.BlockSpec((1, chunk, bd), lambda b, di_, ci: (b, ci, di_))
+    state_in = pl.BlockSpec((1, chunk, N), lambda b, di_, ci: (b, ci, 0))
 
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[seq(di), seq(N), seq(N), seq(di),
-                  pl.BlockSpec((N, di), lambda b, ci: (0, 0)),
-                  pl.BlockSpec((1, di), lambda b, ci: (0, 0))],
-        out_specs=seq(di),
+        in_specs=[chan, state_in, state_in, chan,
+                  pl.BlockSpec((N, bd), lambda b, di_, ci: (0, di_)),
+                  pl.BlockSpec((1, bd), lambda b, di_, ci: (0, di_))],
+        out_specs=chan,
         out_shape=jax.ShapeDtypeStruct((B, S, di), x.dtype),
-        scratch_shapes=[pltpu.VMEM((N, di), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((N, bd), jnp.float32)],
         compiler_params=compat.tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(dt, Bm, Cm, x, A.T, Dskip.reshape(1, di))
 
@@ -227,11 +238,10 @@ def _via_pallas(dt, Bm, Cm, x, A, Dskip, *, chunk=64, initial_state=None,
         dt, Bm, Cm, x, A, Dskip)
 
 
-if compat.HAS_PALLAS:
-    dispatch.register("mamba_scan", "pallas", platforms=("tpu",),
-                      priority=100, supports=_supports_native,
-                      spmd_safe=False)(
-        functools.partial(_via_pallas, interpret=False))
-    dispatch.register("mamba_scan", "interpret", priority=20,
-                      supports=_supports, spmd_safe=False)(
-        functools.partial(_via_pallas, interpret=True))
+dispatch.register("mamba_scan", "pallas", platforms=("tpu",),
+                  priority=100, supports=_supports_native,
+                  spmd_safe=False)(
+    functools.partial(_via_pallas, interpret=False))
+dispatch.register("mamba_scan", "interpret", priority=20,
+                  supports=_supports, spmd_safe=False)(
+    functools.partial(_via_pallas, interpret=True))

@@ -47,14 +47,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
-
-from repro import compat
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import dispatch
-
-if compat.HAS_PALLAS:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
 
 def _identity_constrain(a, dims):
@@ -268,11 +264,10 @@ def _via_pallas(x, gate_vals, expert_idx, wi, wg, wo, *, capacity,
         x, gate_vals, expert_idx, wi, wg, wo)
 
 
-if compat.HAS_PALLAS:
-    dispatch.register("moe_dispatch_combine", "pallas", platforms=("tpu",),
-                      priority=100, supports=_supports_pallas,
-                      spmd_safe=False)(
-        functools.partial(_via_pallas, interpret=False))
-    dispatch.register("moe_dispatch_combine", "interpret", priority=20,
-                      supports=_supports_pallas, spmd_safe=False)(
-        functools.partial(_via_pallas, interpret=True))
+dispatch.register("moe_dispatch_combine", "pallas", platforms=("tpu",),
+                  priority=100, supports=_supports_pallas,
+                  spmd_safe=False)(
+    functools.partial(_via_pallas, interpret=False))
+dispatch.register("moe_dispatch_combine", "interpret", priority=20,
+                  supports=_supports_pallas, spmd_safe=False)(
+    functools.partial(_via_pallas, interpret=True))

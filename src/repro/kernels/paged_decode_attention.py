@@ -29,14 +29,26 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import compat
+
 from . import dispatch
 
 NEG_INF = -1e30
 
 
-def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
-                  l_ref, acc_ref, *, block_size: int, pages: int,
-                  scale: float, kv_heads: int):
+def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
+                  block_size: int, pages: int, scale: float, kv_heads: int,
+                  quant: bool):
+    """One grid step = one slot's page: the whole physical block, every
+    KV head, arrives in VMEM; heads are stepped in a static loop (the
+    per-head K/V rows are a strided load out of the block tile).  With
+    ``quant`` the block is int8 and ``ks_ref``/``vs_ref`` hold its
+    (block_size, KH) f32 scale rows, applied in VMEM right before the
+    dots."""
+    if quant:
+        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
+    else:
+        o_ref, m_ref, l_ref, acc_ref = rest
     pi = pl.program_id(1)
 
     @pl.when(pi == 0)
@@ -45,79 +57,36 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    kv_len = len_ref[pl.program_id(0) // kv_heads]
+    kv_len = len_ref[pl.program_id(0)]
     start = pi * block_size
 
     @pl.when(start < kv_len)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)             # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)       # (bs, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (G, bs)
-        kpos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(kpos < kv_len, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, -1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        for h in range(kv_heads):
+            q = q_ref[0, h].astype(jnp.float32)             # (G, D)
+            k = k_ref[0, :, h, :].astype(jnp.float32)       # (bs, D)
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            if quant:
+                k = k * ks_ref[0, :, h:h + 1]               # (bs, 1) scales
+                v = v * vs_ref[0, :, h:h + 1]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # (G, bs)
+            kpos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(kpos < kv_len, s, NEG_INF)
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * alpha + jnp.sum(p, -1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot(
+                p, v, preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
 
     @pl.when(pi == pages - 1)
     def _finalize():
-        o_ref[0, 0] = (acc_ref[...]
-                       / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
-
-
-def _paged_kernel_quant(bt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref,
-                        vs_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                        block_size: int, pages: int, scale: float,
-                        kv_heads: int):
-    """Int8 variant: k/v blocks arrive as int8 plus per-token-slot f32
-    scale rows (``ks_ref``/``vs_ref``, block shape (1, 1, block_size) from
-    the (NB, KH, bs) transposed scale arrays) DMA'd through the same
-    scalar-prefetched block table; dequantization happens in VMEM right
-    before the dot."""
-    pi = pl.program_id(1)
-
-    @pl.when(pi == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    kv_len = len_ref[pl.program_id(0) // kv_heads]
-    start = pi * block_size
-
-    @pl.when(start < kv_len)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)             # (G, D)
-        ks = ks_ref[0, 0, :]                            # (bs,) f32
-        vs = vs_ref[0, 0, :]
-        k = k_ref[0, :, 0, :].astype(jnp.float32) * ks[:, None]   # (bs, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32) * vs[:, None]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (G, bs)
-        kpos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(kpos < kv_len, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, -1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
-
-    @pl.when(pi == pages - 1)
-    def _finalize():
-        o_ref[0, 0] = (acc_ref[...]
-                       / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...]
+                    / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
@@ -130,10 +99,9 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     valid lengths.  Returns (B, KH, G, D).
 
     With ``k_scale``/``v_scale`` ((NB, bs, KH) f32) the pools are int8;
-    each grid step DMAs the physical block's scale row alongside the
+    each grid step DMAs the physical block's scale rows alongside the
     payload (same scalar-prefetched table dereference) and dequantizes in
-    VMEM.  Scales are transposed to (NB, KH, bs) outside the kernel so
-    their lane axis is the 128-aligned block size."""
+    VMEM."""
     from .ref import normalize_kv_len
 
     B, KH, G, D = q.shape
@@ -144,48 +112,41 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     block_tables = block_tables.astype(jnp.int32)
     quant = k_scale is not None
 
-    pool_spec = pl.BlockSpec((1, bs, 1, D),
-                             lambda bk, pi, bt, ln:
-                             (bt[bk // KH, pi], 0, bk % KH, 0))
-    scale_spec = pl.BlockSpec((1, 1, bs),
-                              lambda bk, pi, bt, ln:
-                              (bt[bk // KH, pi], bk % KH, 0))
-    in_specs = [
-        pl.BlockSpec((1, 1, G, D),
-                     lambda bk, pi, bt, ln: (bk // KH, bk % KH, 0, 0)),
-        pool_spec,
-        pool_spec,
-    ]
+    # whole physical blocks (every KV head): the trailing (KH, D) block
+    # dims equal the pool's, which Mosaic's tiling rule accepts for any KH
+    pool_spec = pl.BlockSpec((1, bs, KH, D),
+                             lambda b, pi, bt, ln: (bt[b, pi], 0, 0, 0))
+    slot_spec = pl.BlockSpec((1, KH, G, D),
+                             lambda b, pi, bt, ln: (b, 0, 0, 0))
+    in_specs = [slot_spec, pool_spec, pool_spec]
     operands = [q, k_pool, v_pool]
     if quant:
+        scale_spec = pl.BlockSpec((1, bs, KH),
+                                  lambda b, pi, bt, ln: (bt[b, pi], 0, 0))
         in_specs += [scale_spec, scale_spec]
-        # (NB, bs, KH) -> (NB, KH, bs): lane axis = block size
-        operands += [k_scale.astype(jnp.float32).transpose(0, 2, 1),
-                     v_scale.astype(jnp.float32).transpose(0, 2, 1)]
-        kernel = functools.partial(_paged_kernel_quant, block_size=bs,
-                                   pages=pages, scale=scale, kv_heads=KH)
-    else:
-        kernel = functools.partial(_paged_kernel, block_size=bs,
-                                   pages=pages, scale=scale, kv_heads=KH)
+        operands += [k_scale.astype(jnp.float32),
+                     v_scale.astype(jnp.float32)]
+    kernel = functools.partial(_paged_kernel, block_size=bs, pages=pages,
+                               scale=scale, kv_heads=KH, quant=quant)
     # Scalar prefetch: the block table (and lengths) are available to the
     # index maps, so the pool blockspec fetches table[b, page] directly.
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B * KH, pages),
+        grid=(B, pages),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, D),
-                               lambda bk, pi, bt, ln:
-                               (bk // KH, bk % KH, 0, 0)),
+        out_specs=slot_spec,
         scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),   # m
-            pltpu.VMEM((G, 1), jnp.float32),   # l
-            pltpu.VMEM((G, D), jnp.float32),   # acc
+            pltpu.VMEM((KH, G, 1), jnp.float32),   # m
+            pltpu.VMEM((KH, G, 1), jnp.float32),   # l
+            pltpu.VMEM((KH, G, D), jnp.float32),   # acc
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KH, G, D), q.dtype),
+        compiler_params=compat.tpu_compiler_params(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables, kv_len, *operands)
 
@@ -212,7 +173,6 @@ def _supports_native(q, k_pool, v_pool, block_tables, kv_len, *,
                      k_scale=None, v_scale=None):
     # Mosaic wants the (G, block_size) score tile lane axis 128-aligned;
     # pools with a smaller block size fall back to the gather backend.
-    # (The transposed scale rows share the same lane axis.)
     return _supports(q, k_pool, v_pool, block_tables, kv_len,
                      k_scale=k_scale, v_scale=v_scale) \
         and k_pool.shape[1] % 128 == 0

@@ -33,17 +33,26 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, state_ref, *,
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    u = u_ref[0].astype(jnp.float32)                     # (N,)
+    N = state_ref.shape[0]
+    u = u_ref[0].astype(jnp.float32)                     # (1, N)
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (N, N), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (N, N), 1))
+
+    def col(row):
+        # (1, N) -> (N, 1) as a masked lane reduction: Mosaic has no
+        # relayout from a lane vector to a sublane column
+        return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
 
     def step(t, S):
-        rt = r_ref[0, 0, t].astype(jnp.float32)          # (N,)
-        kt = k_ref[0, 0, t].astype(jnp.float32)
-        vt = v_ref[0, 0, t].astype(jnp.float32)
-        wt = w_ref[0, 0, t].astype(jnp.float32)
-        kv = kt[:, None] * vt[None, :]                   # (N, N)
-        o = rt @ (S + u[:, None] * kv)                   # (N,)
-        o_ref[0, 0, t] = o.astype(o_ref.dtype)
-        return wt[:, None] * S + kv
+        def row(ref):
+            return ref[0, 0, pl.ds(t, 1), :].astype(jnp.float32)  # (1, N)
+
+        rt, kt, vt, wt = row(r_ref), row(k_ref), row(v_ref), row(w_ref)
+        # o_t = r_t (S + diag(u) k_t^T v_t) = r_t S + (r_t . u k_t) v_t
+        o = (jnp.sum(col(rt) * S, axis=0, keepdims=True)
+             + jnp.sum(rt * u * kt, axis=1, keepdims=True) * vt)
+        o_ref[0, 0, pl.ds(t, 1), :] = o.astype(o_ref.dtype)
+        return col(wt) * S + col(kt) * vt
 
     state_ref[...] = jax.lax.fori_loop(0, chunk, step, state_ref[...])
 
@@ -64,7 +73,9 @@ def wkv6(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
         if ref_kind == "seq":
             return pl.BlockSpec((1, 1, chunk, N),
                                 lambda bh, ci: (bh // H, bh % H, ci, 0))
-        return pl.BlockSpec((1, N), lambda bh, ci: (bh % H, 0))
+        # u rides as (H, 1, N): a (1, N) block of an (H, N) array would
+        # break Mosaic's rule that the trailing block dims tile or match
+        return pl.BlockSpec((1, 1, N), lambda bh, ci: (bh % H, 0, 0))
 
     return pl.pallas_call(
         kernel,
@@ -77,7 +88,7 @@ def wkv6(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
         compiler_params=compat.tpu_compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(r, k, v, w, u)
+    )(r, k, v, w, u.reshape(H, 1, N))
 
 
 # --------------------------------------------------------------------------- #

@@ -1,13 +1,12 @@
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=512")
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_cache")
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "2")
 
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production meshes and extract the roofline terms from the compiled artifact.
 
-The two lines above MUST precede every other import (jax locks the device
+The lines above MUST precede every other import (jax locks the device
 count at first init); do not move them.
 
 Per cell:
@@ -40,6 +39,7 @@ from repro.core import CostModel, find_strategy, BASELINES
 from repro.core.device import (ICI_BW, TPU_V5E_HBM_BW, TPU_V5E_HBM_BYTES,
                                TPU_V5E_PEAK_FLOPS)
 from repro.core.sharding import use_mesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh, production_mesh_spec
 from repro.models import model_module, strategy_to_plan, uniform_plan
 from repro.models.arch import SHAPES
@@ -387,6 +387,7 @@ def main() -> None:
     ap.add_argument("--all", action="store_true")
     ap.add_argument("--skip-existing", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     meshes = {"single": [False], "multi": [True],
               "both": [False, True]}[args.mesh]

@@ -40,37 +40,26 @@ non-slot-shaped memory leaf), with the same warm-up discipline.
 from __future__ import annotations
 
 import argparse
+import os
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat, configs
-from repro.core.device import AxisSpec, ICI_BW, MeshSpec
+from repro import configs
 from repro.core.sharding import use_mesh
 from repro.data import make_dataset
+from repro.kernels import dispatch as kernel_dispatch
 from repro.models import model_module
 from repro.models.arch import ShapeSpec
 from repro.plans import ParallelPlan, STRATEGIES, resolve_plan
 from repro.serve import (PrefixCache, Request, ServeConfig, ServeEngine,
                          make_serve_fns)
 
+from .compile_cache import enable_compile_cache
+from .mesh import host_mesh
 from .train import reduced_arch
-
-
-def serve_mesh(n_dev: int):
-    """Device mesh + cost-model spec for serving.
-
-    Serving wants a model axis when the host has one to give: the decode
-    phase's searched configs shard heads/d_ff over it while batch rides
-    the data axis.
-    """
-    dims = (n_dev // 2, 2) if (n_dev >= 4 and n_dev % 2 == 0) else (n_dev, 1)
-    mesh = compat.make_mesh(dims, ("data", "model"))
-    spec = MeshSpec(axes=(AxisSpec("data", dims[0], ICI_BW),
-                          AxisSpec("model", dims[1], ICI_BW)))
-    return mesh, spec
 
 
 def resolve_serve_plan(arch, mesh_spec, *, plan_path: str = "",
@@ -211,7 +200,7 @@ def _serve_encdec(args, arch, plan) -> None:
         print("  ", row[:24].tolist())
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2.5-3b")
     ap.add_argument("--batch", type=int, default=4,
@@ -273,10 +262,12 @@ def main() -> None:
     ap.add_argument("--save-plan", default="",
                     help="write the plan (searched or baseline) to this "
                          "JSON path for later --plan runs")
-    ap.add_argument("--width", type=int, default=256)
-    ap.add_argument("--depth", type=int, default=4)
-    ap.add_argument("--vocab", type=int, default=512)
-    ap.add_argument("--experts", type=int, default=4)
+    ap.add_argument("--width", type=int, default=0,
+                    help="cut d_model to this width (0 = published)")
+    ap.add_argument("--depth", type=int, default=0,
+                    help="cut the layer count to this depth (0 = published)")
+    ap.add_argument("--vocab", type=int, default=0)
+    ap.add_argument("--experts", type=int, default=0)
     ap.add_argument("--kernel-backend", default="",
                     help="force a kernel dispatch backend "
                          "(pallas|interpret|xla|ref) for every op — "
@@ -291,17 +282,24 @@ def main() -> None:
                          "autotune cache (default ~/.cache/repro/autotune; "
                          "same as REPRO_AUTOTUNE_CACHE_DIR) — a restart on "
                          "the same device kind skips re-tuning")
-    args = ap.parse_args()
-    if args.autotune_cache_dir:
-        import os
+    return ap
 
-        from repro.kernels import dispatch as kernel_dispatch
+
+def run(argv=None) -> dict:
+    """Build the plan and the engine, serve the synthetic requests, print
+    the report and return it: ``completions``, ``compile_s`` (warm-up),
+    ``wall_s`` and the engine ``stats``, plus the ``arch`` and
+    ``params`` served (so a caller can check the logits against the
+    model).  The encoder-decoder arch returns ``completions=None``."""
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
+    if args.autotune_cache_dir:
         os.environ[kernel_dispatch.ENV_CACHE_DIR] = args.autotune_cache_dir
 
     arch = reduced_arch(configs.get(args.arch), args.width, args.depth,
                         args.vocab, args.experts)
     n_dev = jax.device_count()
-    mesh, mesh_spec = serve_mesh(n_dev)
+    mesh, mesh_spec = host_mesh(n_dev)
     max_len = args.prompt_len + args.gen
     # the plan prices decode with the chunk budget the engine will run;
     # mirror ServeEngine's auto default (2*block_size paged, 256 dense)
@@ -320,7 +318,7 @@ def main() -> None:
     if arch.enc_layers:
         with use_mesh(mesh if n_dev > 1 else None):
             _serve_encdec(args, arch, plan)
-        return
+        return {"arch": arch, "completions": None}
 
     mod = model_module(arch)
     n_requests = args.requests or 2 * args.batch
@@ -399,6 +397,13 @@ def main() -> None:
     print("sample generations (token ids):")
     for c in sorted(completions, key=lambda c: c.uid)[:2]:
         print(f"  uid={c.uid} [{c.finish_reason}]", c.tokens[:24])
+    return {"arch": arch, "params": params, "requests": requests,
+            "completions": completions, "compile_s": t_compile,
+            "wall_s": wall, "stats": dict(s)}
+
+
+def main() -> None:
+    run()
 
 
 if __name__ == "__main__":

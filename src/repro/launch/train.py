@@ -18,24 +18,25 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
+import math
+import os
 import time
 from pathlib import Path
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro import compat, configs
 from repro.checkpoint import CheckpointManager
-from repro.core.device import AxisSpec, ICI_BW, MeshSpec
 from repro.core.sharding import use_mesh
 from repro.data import make_dataset
 from repro.kernels import dispatch as kernel_dispatch
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import host_mesh
 from repro.models import model_module
 from repro.models.arch import ShapeSpec
 from repro.optim import AdamWConfig, adamw_init
-from repro.plans import (batch_pspecs, param_pspecs, resolve_plan,
-                         to_shardings)
+from repro.plans import param_pspecs, resolve_plan, to_shardings
 from repro.train import TrainConfig, make_train_step
 
 
@@ -60,7 +61,7 @@ def reduced_arch(arch, width, depth, vocab, experts):
     return dataclasses.replace(arch, **kw) if kw else arch
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b")
     ap.add_argument("--steps", type=int, default=100)
@@ -89,11 +90,15 @@ def main() -> None:
                     help="write the plan (searched or baseline) to this "
                          "JSON path; reload with --plan here or on the "
                          "serve driver")
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
+    ap.add_argument("--ckpt-dir", default="results/ckpt")
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--lr", type=float, default=3e-3)
     ap.add_argument("--log-every", type=int, default=10)
+    ap.add_argument("--overfit-batches", type=int, default=0,
+                    help="cycle the first N batches of the stream instead "
+                         "of fresh ones (0 = off): a sanity run in which the "
+                         "loss must fall, whatever the data's entropy")
     ap.add_argument("--metrics-out", default="")
     ap.add_argument("--kernel-backend", default="",
                     help="force a kernel dispatch backend "
@@ -108,20 +113,42 @@ def main() -> None:
                     help="measured DeviceProfile JSON (launch.profile); "
                          "calibrates the plan search's cost model to this "
                          "host instead of the analytic constants")
-    args = ap.parse_args()
+    return ap
+
+
+def weight_shards(params) -> list[dict]:
+    """Per matmul weight (ndim >= 2): its global shape, the distinct
+    shard shapes its addressable shards hold, and over how many devices —
+    the proof that a plan's sharding is physical, not just annotated."""
+    out = []
+    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
+        if leaf.ndim < 2:
+            continue
+        shards = leaf.addressable_shards
+        out.append({"name": jax.tree_util.keystr(path),
+                    "shape": tuple(leaf.shape),
+                    "shard_shapes": sorted({tuple(s.data.shape)
+                                            for s in shards}),
+                    "devices": len({s.device for s in shards})})
+    return out
+
+
+def run(argv=None) -> dict:
+    """Search (or load) a plan, train, and return what happened:
+    per-step ``history`` (every step is logged), ``compile_s`` (the
+    first step, trace + compile + run), ``step_s`` (mean of the later
+    steps, each waited on), the plan name and ``weight_shards`` of the
+    final parameters."""
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
     if args.autotune_cache_dir:
-        import os
         os.environ[kernel_dispatch.ENV_CACHE_DIR] = args.autotune_cache_dir
 
     arch = reduced_arch(configs.get(args.arch), args.width, args.depth,
                         args.vocab, args.experts)
     shape = ShapeSpec("custom", args.seq, args.batch, "train")
     n_dev = jax.device_count()
-
-    # mesh over available devices: prefer pure-data on small hosts
-    mesh = compat.make_mesh((n_dev, 1), ("data", "model"))
-    mesh_spec = MeshSpec(axes=(AxisSpec("data", n_dev, ICI_BW),
-                               AxisSpec("model", 1, ICI_BW)))
+    mesh, mesh_spec = host_mesh(n_dev)
 
     name = {"search": "searched", "none": "uniform"}.get(
         args.strategy, args.strategy)
@@ -154,7 +181,8 @@ def main() -> None:
         arch, pplan if train_stages.num_stages > 1 else plan, tcfg)
     ds = make_dataset(arch, shape)
 
-    ckpt = CheckpointManager(args.ckpt_dir)
+    ckpt = (CheckpointManager(args.ckpt_dir)
+            if args.ckpt_every or args.resume else None)
     init = mod.init_encdec if arch.enc_layers else mod.init_lm
     params = init(jax.random.PRNGKey(0), arch, jnp.float32)
     opt_state = adamw_init(params)
@@ -169,32 +197,62 @@ def main() -> None:
 
     p_sh = to_shardings(param_pspecs(params, arch, plan, stages=train_stages),
                         mesh, like=params)
+    # moments shard like their parameters; pinning the step's output
+    # shardings to the input ones keeps step 2 from recompiling
+    o_sh = {"m": p_sh, "v": p_sh,
+            "step": jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())}
     params = jax.device_put(params, p_sh)
-    jit_step = jax.jit(step_fn, donate_argnums=(0, 1))
+    opt_state = jax.device_put(opt_state, o_sh)
+    jit_step = jax.jit(step_fn, donate_argnums=(0, 1),
+                       out_shardings=(p_sh, o_sh, None))
 
-    history = []
+    history, step_times = [], []
     with use_mesh(mesh):
-        t0 = time.time()
         for step in range(start_step, args.steps):
-            batch = jax.tree.map(jnp.asarray, ds.batch_at(step))
+            data_step = (step % args.overfit_batches if args.overfit_batches
+                         else step)
+            batch = jax.tree.map(jnp.asarray, ds.batch_at(data_step))
+            t0 = time.perf_counter()
             params, opt_state, metrics = jit_step(params, opt_state, batch)
+            m = {k: float(v) for k, v in metrics.items()}   # waits
+            step_times.append(time.perf_counter() - t0)
+            history.append({"step": step, **m})
             if step % args.log_every == 0 or step == args.steps - 1:
-                m = {k: float(v) for k, v in metrics.items()}
-                dt = time.time() - t0
-                tok_s = shape.tokens * (step - start_step + 1) / max(dt, 1e-9)
                 print(f"step {step:5d} loss={m['loss']:.4f} "
                       f"nll={m['nll']:.4f} acc={m['accuracy']:.3f} "
-                      f"gnorm={m['grad_norm']:.2f} tok/s={tok_s:.0f}")
-                history.append({"step": step, **m})
+                      f"gnorm={m['grad_norm']:.2f} "
+                      f"step_s={step_times[-1]:.4f}")
             if args.ckpt_every and (step + 1) % args.ckpt_every == 0:
                 ckpt.save(step + 1, {"params": params, "opt": opt_state})
     if args.ckpt_every:
         ckpt.save(args.steps, {"params": params, "opt": opt_state})
     if args.metrics_out:
-        Path(args.metrics_out).write_text(json.dumps(history, indent=1))
-    first, last = history[0]["nll"], history[-1]["nll"]
-    print(f"nll {first:.4f} -> {last:.4f} "
-          f"({'LEARNED' if last < first - 0.2 else 'check'})")
+        out = Path(args.metrics_out)
+        out.parent.mkdir(parents=True, exist_ok=True)
+        out.write_text(json.dumps(history, indent=1))
+    steady = step_times[1:]
+    return {"arch": arch.name, "vocab": arch.vocab,
+            "plan": pplan.strategy_name,
+            "mesh": dict(zip(mesh.axis_names, mesh.devices.shape)),
+            "tokens_per_step": shape.tokens, "history": history,
+            "compile_s": step_times[0] if step_times else 0.0,
+            "step_s": sum(steady) / len(steady) if steady else 0.0,
+            "weight_shards": weight_shards(params)}
+
+
+def main() -> None:
+    res = run()
+    hist = res["history"]
+    if not hist:
+        return
+    first, last = hist[0]["nll"], hist[-1]["nll"]
+    print(f"compile {res['compile_s']:.2f} s (first step), steady "
+          f"{res['step_s']:.4f} s/step")
+    print(f"nll {first:.4f} -> {last:.4f}")
+    if not all(math.isfinite(h["loss"]) for h in hist):
+        raise SystemExit("non-finite loss")
+    if len(hist) > 1 and not last < first:
+        raise SystemExit(f"nll did not fall: {first:.4f} -> {last:.4f}")
 
 
 if __name__ == "__main__":

@@ -97,3 +97,43 @@ def test_persistence_can_be_disabled(tmp_path, monkeypatch):
     monkeypatch.setenv(dispatch.ENV_PERSIST, "0")
     _tune()
     assert not any(tmp_path.iterdir())
+
+
+def test_candidate_that_fails_to_compile_is_counted_and_logged(
+        monkeypatch, caplog):
+    """A timed candidate that raises is skipped, logged at warning and
+    counted — never dropped in silence."""
+    from repro import compat
+    monkeypatch.setattr(compat, "default_platform", lambda: "tpu")
+
+    def bench(b):
+        if b == 256:
+            raise RuntimeError("Mosaic refused the block")
+
+    with caplog.at_level("WARNING", logger=dispatch.__name__):
+        got = dispatch.tuned_blocks("op_fail", ("k",), [(256,), (128,)],
+                                    bench=bench, args=())
+    assert got == (128,)
+    assert dispatch.autotune_cache_stats().get("compile_failures") == 1
+    assert any("failed" in r.getMessage() for r in caplog.records)
+
+
+def test_compile_cache_dir_env_or_checkout(monkeypatch, tmp_path):
+    """The persistent compilation cache honours JAX_COMPILATION_CACHE_DIR
+    (JAX reads it; the helper sets nothing) and otherwise sits at a fixed
+    ``.jax_cache`` in the checkout."""
+    from pathlib import Path
+
+    from repro.launch import compile_cache
+
+    updates = []
+    monkeypatch.setattr(compile_cache.jax.config, "update",
+                        lambda *a: updates.append(a))
+    monkeypatch.setenv(compile_cache.ENV, str(tmp_path))
+    assert compile_cache.enable_compile_cache() == tmp_path
+    assert updates == []
+    monkeypatch.delenv(compile_cache.ENV)
+    got = compile_cache.enable_compile_cache()
+    root = Path(__file__).resolve().parents[1]
+    assert got == root / ".jax_cache"
+    assert updates == [("jax_compilation_cache_dir", str(got))]

@@ -59,18 +59,24 @@ def test_workflow_has_weekly_schedule_trigger():
 
 
 def test_workflow_test_job_runs_tier1_on_jax_matrix():
+    """CI tests one installation: the JAX pinned in the workflow is the
+    one installed here, and no job installs another."""
+    import jax
     wf = _load()
     job = wf["jobs"]["test"]
-    include = job["strategy"]["matrix"]["include"]
-    pins = {m["jax"] for m in include}
-    assert "==0.4.37" in pins          # the supported 0.4.x floor
-    assert "" in pins                  # latest release
+    assert "strategy" not in job       # no version matrix
     runs = _all_run_lines(job)
+    pin = f'"jax[cpu]=={jax.__version__}"'
+    assert pin in runs
+    for name, other in wf["jobs"].items():
+        for line in _all_run_lines(other).splitlines():
+            if "jax[cpu]" in line:
+                assert pin in line, (name, line)
     assert "python -m pytest -x -q" in runs
     # without a YAML parser this module skips in CI — the guards would
     # silently stop guarding
     assert "pyyaml" in runs
-    # pip caching keeps the matrix fast
+    # pip caching keeps the job fast
     setups = [s for s in job["steps"]
               if str(s.get("uses", "")).startswith("actions/setup-python")]
     assert setups and setups[0]["with"].get("cache") == "pip"

@@ -291,3 +291,67 @@ def test_tuned_blocks_caches_heuristic():
     if compat.default_platform() != "tpu":
         assert calls == []  # benchmarking never runs off-TPU
     dispatch.clear_autotune_cache()
+
+
+# --------------------------------------------------------------------------- #
+# nothing hides the device: interpret is never automatic, every
+# resolution is counted, an unknown TPU kind is refused
+# --------------------------------------------------------------------------- #
+@pytest.fixture
+def fake_op():
+    """An op whose only eligible impl is the interpreter."""
+    dispatch.register("op_fake", "interpret", priority=20)(lambda x: x)
+    dispatch.register("op_fake", "xla", priority=50,
+                      supports=lambda x: False)(lambda x: x)
+    yield "op_fake"
+    dispatch._REGISTRY.pop("op_fake", None)
+
+
+def test_interpret_is_never_an_automatic_choice(fake_op, monkeypatch):
+    x = jnp.ones((4,))
+    with pytest.raises(RuntimeError, match="no eligible backend"):
+        dispatch.select(fake_op, x)
+    assert dispatch.select(fake_op, x, backend="interpret").backend == \
+        "interpret"
+    # on a TPU host the real ops resolve to pallas or a production
+    # backend, never to the interpreter
+    monkeypatch.setattr(compat, "default_platform", lambda: "tpu")
+    q, k, v = _flash_args(S=256, D=128)
+    assert dispatch.select("flash_attention", q, k, v).backend == "pallas"
+    q, k, v = _flash_args(S=100)         # unaligned: no native kernel
+    assert dispatch.select("flash_attention", q, k, v).backend in (
+        "ref", "xla")
+    r, kk, vv, w, u = _wkv_args()
+    s0 = jnp.zeros((1, 2, 32, 32), jnp.float32)
+    assert dispatch.select("wkv6", r, kk, vv, w, u, initial_state=s0,
+                           return_state=True).backend in ("ref", "xla")
+
+
+def test_selection_counts_and_spmd_skips(monkeypatch):
+    dispatch.reset_selection_counts()
+    q, k, v = _flash_args()
+    for _ in range(2):
+        dispatch.call("flash_attention", q, k, v, backend="ref")
+    dispatch.call("flash_attention", q, k, v, backend="xla")
+    assert dispatch.selection_counts() == {("flash_attention", "ref"): 2,
+                                           ("flash_attention", "xla"): 1}
+    assert dispatch.spmd_skips() == {}
+    # under a multi-device mesh the spmd-unsafe native kernel is passed
+    # over, and the skip is on record
+    monkeypatch.setattr(compat, "default_platform", lambda: "tpu")
+    monkeypatch.setattr(dispatch, "_mesh_active", lambda: True)
+    q, k, v = _flash_args(S=256, D=128)
+    assert dispatch.select("flash_attention", q, k, v).backend != "pallas"
+    assert dispatch.spmd_skips() == {("flash_attention", "pallas"): 1}
+    dispatch.reset_selection_counts()
+    assert dispatch.selection_counts() == {} and dispatch.spmd_skips() == {}
+
+
+def test_chip_spec_by_device_kind():
+    from repro.core.device import TPU_V5E, chip_for
+    assert chip_for("tpu", "TPU v5 lite") is TPU_V5E
+    with pytest.raises(ValueError, match="unknown TPU device_kind"):
+        chip_for("tpu", "TPU v99 imaginary")
+    said = []
+    assert chip_for("cpu", "cpu", log=said.append) is TPU_V5E
+    assert said and "v5e" in said[0]

@@ -1,0 +1,141 @@
+"""Every Pallas kernel compiles with Mosaic for a TPU v5e at published
+widths.
+
+Nothing runs: each kernel (and, for training, its reference-VJP
+backward) is lowered and compiled for one chip of a *described* v5e:2x2
+topology, so Mosaic refuses here what it would refuse on the chip —
+unaligned block shapes, unsupported relayouts, too much VMEM — while the
+rest of the suite runs the same kernels in interpret mode on the CPU.
+
+The topology is described inside a module fixture (never at import): the
+TPU compiler library is process-exclusive, and describing it while
+pytest-xdist workers import this file would make the workers collect
+different tests.  The persistent compilation cache stays off around the
+compiles: an entry compiled for a described chip cannot be read back
+without one.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro import configs
+from repro.kernels import dispatch
+from repro.models.moe import capacity
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield jax.sharding.SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _pallas(op):
+    return dispatch.backends(op)["pallas"].fn
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+F32, I32 = jnp.float32, jnp.int32
+OLMO = configs.get("olmo-1b")
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_train_step_compiles(one_chip, dtype):
+    """olmo-1b training attention: seq 2048, batch 4, the drivers'
+    query chunk 256; forward kernel plus the reference backward."""
+    B, S, H, D = 4, 2048, OLMO.n_heads, OLMO.hd
+    fwd = _pallas("flash_attention")
+
+    def loss(q, k, v):
+        return fwd(q, k, v, causal=True, block_q=256).astype(F32).sum()
+
+    shape = ((B, H, S, D), dtype)
+    _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), one_chip, shape, shape, shape)
+
+
+def test_decode_attention_compiles(one_chip):
+    """olmo-1b dense-cache decode: 8 slots over a 4096-token cache."""
+    B, T, KH, D = 8, 4096, OLMO.n_kv_heads, OLMO.hd
+    G = OLMO.n_heads // KH
+    _compile(_pallas("decode_attention"), one_chip, ((B, KH, G, D), F32),
+             ((B, KH, T, D), F32), ((B, KH, T, D), F32), ((B,), I32))
+
+
+@pytest.mark.parametrize("quant", [False, True])
+def test_paged_decode_attention_compiles(one_chip, quant):
+    """olmo-1b paged decode as the serve engine runs it: 8 slots, block
+    size 128, 5 pages per slot, fp or int8 pool with f32 scales."""
+    B, pages, bs, KH, D = 8, 5, 128, OLMO.n_kv_heads, OLMO.hd
+    NB, G = B * pages + 1, OLMO.n_heads // KH
+    pool = ((NB, bs, KH, D), jnp.int8 if quant else F32)
+    shapes = [((B, KH, G, D), F32), pool, pool, ((B, pages), I32),
+              ((B,), I32)]
+    fn = _pallas("paged_decode_attention")
+    if quant:
+        shapes += [((NB, bs, KH), F32)] * 2
+        _compile(lambda q, k, v, bt, n, ks, vs: fn(
+            q, k, v, bt, n, k_scale=ks, v_scale=vs), one_chip, *shapes)
+    else:
+        _compile(fn, one_chip, *shapes)
+
+
+def test_wkv6_compiles(one_chip):
+    """rwkv6-1b6 time mixing: 32 heads of size 64, seq 2048."""
+    arch = configs.get("rwkv6-1b6")
+    B, T, H, N = 2, 2048, arch.n_rwkv_heads, arch.rwkv_head_size
+    seq = ((B, H, T, N), F32)
+    fwd = _pallas("wkv6")
+
+    def loss(r, k, v, w, u):
+        return fwd(r, k, v, w, u).sum()
+
+    _compile(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)), one_chip,
+             seq, seq, seq, seq, ((H, N), F32))
+
+
+def test_mamba_scan_compiles(one_chip):
+    """jamba-1.5-large selective scan: d_inner 16384, state 16, seq
+    2048."""
+    arch = configs.get("jamba-1.5-large")
+    B, S, di, N = 1, 2048, arch.d_inner, arch.ssm_state
+    fwd = _pallas("mamba_scan")
+
+    def loss(dt, Bm, Cm, x, A, Dk):
+        return fwd(dt, Bm, Cm, x, A, Dk).sum()
+
+    _compile(jax.value_and_grad(loss, argnums=(0, 1, 2, 3)), one_chip,
+             ((B, S, di), F32), ((B, S, N), F32), ((B, S, N), F32),
+             ((B, S, di), F32), ((di, N), F32), ((di,), F32))
+
+
+def test_moe_dispatch_combine_compiles(one_chip):
+    """phi-3.5-MoE dispatch/combine at a shape the Pallas backend accepts:
+    d_model 4096, 16 experts top-2, a 32-token chunk on each of 8 rows."""
+    arch = configs.get("phi3.5-moe-42b-a6.6b")
+    B, S, D, E, K = 8, 32, arch.d_model, arch.n_experts, arch.top_k
+    F = arch.moe_d_ff
+    C = capacity(S, arch)
+    fn = _pallas("moe_dispatch_combine")
+    _compile(lambda x, g, i, wi, wg, wo: fn(x, g, i, wi, wg, wo, capacity=C),
+             one_chip, ((B, S, D), F32), ((B, S, K), F32),
+             ((B, S, K), I32), ((E, D, F), F32), ((E, D, F), F32),
+             ((E, F, D), F32))

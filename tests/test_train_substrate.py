@@ -97,6 +97,27 @@ def test_train_loss_decreases():
     assert last < first - 0.3, (first, last)
 
 
+def test_train_driver_run_overfits_one_batch(tmp_path, monkeypatch):
+    """``launch.train.run`` as the chip smoke calls it: every step is in
+    the history, one repeated batch drives the nll down, and a run with
+    checkpointing off writes nothing."""
+    from repro.launch import train
+
+    monkeypatch.chdir(tmp_path)
+    # the persistent cache would stay on for the rest of this worker
+    monkeypatch.setattr(train, "enable_compile_cache", lambda: None)
+    res = train.run(["--arch", "olmo-1b", "--width", "64", "--depth", "2",
+                     "--vocab", "128", "--seq", "32", "--batch", "2",
+                     "--steps", "8", "--ckpt-every", "0",
+                     "--overfit-batches", "1"])
+    nll = [h["nll"] for h in res["history"]]
+    assert [h["step"] for h in res["history"]] == list(range(8))
+    assert nll[-1] < nll[0] - 0.5, nll
+    assert res["mesh"] == {"data": 1, "model": 1}
+    assert all(w["devices"] == 1 for w in res["weight_shards"])
+    assert not any(tmp_path.iterdir())
+
+
 # --------------------------------------------------------------------------- #
 # checkpoint fault tolerance
 # --------------------------------------------------------------------------- #
