@@ -119,6 +119,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         compiler_params=compat.tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q, k, v)
 
 

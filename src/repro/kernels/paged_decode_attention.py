@@ -148,6 +148,7 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         compiler_params=compat.tpu_compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_decode_attention",
     )(block_tables, kv_len, *operands)
 
 

@@ -58,17 +58,13 @@ def forward(params, batch: dict, arch: ArchConfig,
     memory = encode(params, batch["frames"], arch, plan, q_chunk=q_chunk)
     mpos = jnp.arange(memory.shape[1])
     tokens = batch["tokens"]
-    h = L.embed(params["embed"], tokens, plan.embed)
+    h = lm.embed_tokens(params, tokens, plan)
     positions = jnp.arange(tokens.shape[1])
     h, aux, _ = lm.run_stack(h, params["stack"], arch, plan.segments,
                              positions=positions, causal=True,
                              memory=(memory, mpos), q_chunk=q_chunk,
                              remat=remat)
-    h = L.apply_norm(params["final_norm"], h)
-    h = constrain(h, plan.final_norm, ("batch", "seq", "d_model"))
-    logits = L.lm_head(params["lm_head"], h, params["embed"], arch,
-                       plan.lm_head)
-    return logits, aux
+    return lm.head(params, lm.final_norm(params, h, plan), arch, plan), aux
 
 
 def loss_fn(params, batch: dict, arch: ArchConfig,
@@ -78,14 +74,13 @@ def loss_fn(params, batch: dict, arch: ArchConfig,
     memory = encode(params, batch["frames"], arch, plan, q_chunk=q_chunk)
     mpos = jnp.arange(memory.shape[1])
     tokens = batch["tokens"]
-    h = L.embed(params["embed"], tokens, plan.embed)
+    h = lm.embed_tokens(params, tokens, plan)
     positions = jnp.arange(tokens.shape[1])
     h, aux, _ = lm.run_stack(h, params["stack"], arch, plan.segments,
                              positions=positions, causal=True,
                              memory=(memory, mpos), q_chunk=q_chunk,
                              remat=remat)
-    h = L.apply_norm(params["final_norm"], h)
-    h = constrain(h, plan.final_norm, ("batch", "seq", "d_model"))
+    h = lm.final_norm(params, h, plan)
     loss, metrics = lm.chunked_lm_loss(h[:, :-1, :], tokens[:, 1:],
                                        params, arch, plan, chunk=loss_chunk)
     metrics["loss"] = loss
@@ -100,16 +95,14 @@ def prefill(params, batch: dict, cache: dict, arch: ArchConfig,
     memory = encode(params, batch["frames"], arch, plan, q_chunk=q_chunk)
     mpos = jnp.arange(memory.shape[1])
     tokens = batch["tokens"]
-    h = L.embed(params["embed"], tokens, plan.embed)
+    h = lm.embed_tokens(params, tokens, plan)
     positions = jnp.arange(tokens.shape[1])
     h, _, cache_dec = lm.run_stack(
         h, params["stack"], arch, plan.segments, positions=positions,
         causal=True, cache=cache["dec"], cache_pos=0,
         memory=(memory, mpos), q_chunk=q_chunk, remat=False)
-    h = L.apply_norm(params["final_norm"], h[:, -1:, :])
-    logits = L.lm_head(params["lm_head"], h, params["embed"], arch,
-                       plan.lm_head)
-    return logits, {"dec": cache_dec, "memory": memory}
+    h = lm.final_norm(params, h[:, -1:, :], plan)
+    return lm.head(params, h, arch, plan), {"dec": cache_dec, "memory": memory}
 
 
 def decode_step(params, token: jax.Array, cache: dict, pos,
@@ -117,16 +110,14 @@ def decode_step(params, token: jax.Array, cache: dict, pos,
     plan = plan if plan is not None else uniform_plan(arch)
     memory = cache["memory"]
     mpos = jnp.arange(memory.shape[1])
-    h = L.embed(params["embed"], token, plan.embed)
+    h = lm.embed_tokens(params, token, plan)
     positions, cache_pos = lm.decode_positions(pos, token.shape[0])
     h, _, cache_dec = lm.run_stack(
         h, params["stack"], arch, plan.segments, positions=positions,
         causal=True, cache=cache["dec"], cache_pos=cache_pos,
         memory=(memory, mpos), remat=False)
-    h = L.apply_norm(params["final_norm"], h)
-    logits = L.lm_head(params["lm_head"], h, params["embed"], arch,
-                       plan.lm_head)
-    return logits, {"dec": cache_dec, "memory": memory}
+    h = lm.final_norm(params, h, plan)
+    return lm.head(params, h, arch, plan), {"dec": cache_dec, "memory": memory}
 
 
 def init_cache(arch: ArchConfig, batch: int, max_len: int,

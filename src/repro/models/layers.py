@@ -27,6 +27,8 @@ from repro.kernels import dispatch as kernel_dispatch
 from repro.kernels.mha_xla import mha_chunked as _mha_core  # noqa: F401
 from repro.kernels.quant import quantize_kv
 
+from .plan import node_scope
+
 # --------------------------------------------------------------------------- #
 # init helpers
 # --------------------------------------------------------------------------- #
@@ -371,14 +373,18 @@ def init_mlp(key, arch, dtype, d_ff: int | None = None):
     }
 
 
-def mlp(p: dict, x: jax.Array, cfg_in: LayerConfig,
-        cfg_out: LayerConfig) -> jax.Array:
-    h = jnp.einsum("bsd,df->bsf", x, p["wi"])
-    g = jnp.einsum("bsd,df->bsf", x, p["wg"])
-    h = jax.nn.silu(g) * h
-    h = constrain(h, cfg_in, ("batch", "seq", "d_ff"))
-    y = jnp.einsum("bsf,fd->bsd", h, p["wo"])
-    return constrain(y, cfg_out, ("batch", "seq", "d_model"))
+def mlp(p: dict, x: jax.Array, sub: dict) -> jax.Array:
+    """``sub`` is the pattern layer's sublayer plan: the ``wi``/``wg``
+    products and the activation are node ``mlp_in``, ``wo`` is
+    ``mlp_out``."""
+    with node_scope(sub, "mlp_in") as cfg:
+        h = jnp.einsum("bsd,df->bsf", x, p["wi"])
+        g = jnp.einsum("bsd,df->bsf", x, p["wg"])
+        h = jax.nn.silu(g) * h
+        h = constrain(h, cfg, ("batch", "seq", "d_ff"))
+    with node_scope(sub, "mlp_out") as cfg:
+        y = jnp.einsum("bsf,fd->bsd", h, p["wo"])
+        return constrain(y, cfg, ("batch", "seq", "d_model"))
 
 
 # --------------------------------------------------------------------------- #

@@ -36,7 +36,10 @@ from . import layers as L
 from . import moe as M
 from . import recurrent as Rc
 from .arch import ArchConfig
-from .plan import ModelPlan, Segment, UnitPlan, sublayer_keys, uniform_plan
+from .plan import (ModelPlan, Segment, UnitPlan, node_scope, sublayer_keys,
+                   uniform_plan)
+
+BSD = ("batch", "seq", "d_model")
 
 
 # --------------------------------------------------------------------------- #
@@ -110,84 +113,93 @@ def unit_forward(h, unit_params, arch: ArchConfig, unit_plan: UnitPlan,
         lc = cache.get(f"l{j}") if cache is not None else None
         nc: dict = {}
 
-        hn = L.apply_norm(lp["ln1"], h)
-        hn = constrain(hn, sub["ln1"], ("batch", "seq", "d_model"))
+        with node_scope(sub, "ln1") as cfg:
+            hn = constrain(L.apply_norm(lp["ln1"], h), cfg, BSD)
         if spec.mixer == "attn":
-            a, kvc = L.attention(
-                lp["attn"], hn, arch, sub["attn"], positions=positions,
-                causal=causal, kv_cache=(lc or {}).get("kv"),
-                cache_pos=cache_pos, block_tables=block_tables,
-                q_lens=q_lens, q_chunk=q_chunk)
-            y = L.attention_out(lp["attn"], a, sub["attn_out"])
+            with node_scope(sub, "attn") as cfg:
+                a, kvc = L.attention(
+                    lp["attn"], hn, arch, cfg, positions=positions,
+                    causal=causal, kv_cache=(lc or {}).get("kv"),
+                    cache_pos=cache_pos, block_tables=block_tables,
+                    q_lens=q_lens, q_chunk=q_chunk)
+            with node_scope(sub, "attn_out") as cfg:
+                y = L.attention_out(lp["attn"], a, cfg)
             if kvc is not None:
                 nc["kv"] = kvc
         elif spec.mixer == "mamba":
-            if cache is None:
-                # hierarchical remat: during the unit's bwd recompute only
-                # one mixer's scan internals are live at a time
-                y = jax.checkpoint(
-                    lambda p_, h_: Rc.mamba_mix(
-                        p_, h_, arch, sub["ssm"], chunk=time_chunk)[0],
-                    policy=jax.checkpoint_policies.nothing_saveable,
-                )(lp["ssm"], hn)
-            else:
-                y, st = Rc.mamba_mix(lp["ssm"], hn, arch, sub["ssm"],
-                                     state=lc.get("ssm_state"),
-                                     chunk=time_chunk, q_lens=q_lens)
-                nc["ssm_state"] = st
+            with node_scope(sub, "ssm") as cfg:
+                if cache is None:
+                    # hierarchical remat: during the unit's bwd recompute
+                    # only one mixer's scan internals are live at a time
+                    y = jax.checkpoint(
+                        lambda p_, h_: Rc.mamba_mix(
+                            p_, h_, arch, cfg, chunk=time_chunk)[0],
+                        policy=jax.checkpoint_policies.nothing_saveable,
+                    )(lp["ssm"], hn)
+                else:
+                    y, st = Rc.mamba_mix(lp["ssm"], hn, arch, cfg,
+                                         state=lc.get("ssm_state"),
+                                         chunk=time_chunk, q_lens=q_lens)
+                    nc["ssm_state"] = st
         elif spec.mixer == "rwkv":
-            if cache is None:
-                y = jax.checkpoint(
-                    lambda p_, h_: Rc.rwkv_tmix(
-                        p_, h_, arch, sub["tmix"], chunk=time_chunk)[0],
-                    policy=jax.checkpoint_policies.nothing_saveable,
-                )(lp["tmix"], hn)
-            else:
-                y, st = Rc.rwkv_tmix(lp["tmix"], hn, arch, sub["tmix"],
-                                     state=lc.get("tmix_state"),
-                                     chunk=time_chunk, q_lens=q_lens)
-                nc["tmix_state"] = st
+            with node_scope(sub, "tmix") as cfg:
+                if cache is None:
+                    y = jax.checkpoint(
+                        lambda p_, h_: Rc.rwkv_tmix(
+                            p_, h_, arch, cfg, chunk=time_chunk)[0],
+                        policy=jax.checkpoint_policies.nothing_saveable,
+                    )(lp["tmix"], hn)
+                else:
+                    y, st = Rc.rwkv_tmix(lp["tmix"], hn, arch, cfg,
+                                         state=lc.get("tmix_state"),
+                                         chunk=time_chunk, q_lens=q_lens)
+                    nc["tmix_state"] = st
         else:
             raise ValueError(spec.mixer)
-        h = h + y
-        h = constrain(h, sub["add1"], ("batch", "seq", "d_model"))
+        with node_scope(sub, "add1") as cfg:
+            h = constrain(h + y, cfg, BSD)
 
         if memory is not None:
-            hx = L.apply_norm(lp["ln_x"], h)
-            hx = constrain(hx, sub["ln_x"], ("batch", "seq", "d_model"))
-            mem_h, mpos = memory
-            mk = jnp.einsum("bsd,dhe->bshe", mem_h, lp["xattn"]["wk"])
-            mv = jnp.einsum("bsd,dhe->bshe", mem_h, lp["xattn"]["wv"])
-            a, _ = L.attention(
-                lp["xattn"], hx, arch, sub["xattn"], positions=positions,
-                causal=False, kv_override=(mk, mv, mpos), q_chunk=q_chunk,
-                use_rope=False)
-            h = h + L.attention_out(lp["xattn"], a, sub["xattn_out"])
-            h = constrain(h, sub["add_x"], ("batch", "seq", "d_model"))
+            with node_scope(sub, "ln_x") as cfg:
+                hx = constrain(L.apply_norm(lp["ln_x"], h), cfg, BSD)
+            with node_scope(sub, "xattn") as cfg:
+                mem_h, mpos = memory
+                mk = jnp.einsum("bsd,dhe->bshe", mem_h, lp["xattn"]["wk"])
+                mv = jnp.einsum("bsd,dhe->bshe", mem_h, lp["xattn"]["wv"])
+                a, _ = L.attention(
+                    lp["xattn"], hx, arch, cfg, positions=positions,
+                    causal=False, kv_override=(mk, mv, mpos),
+                    q_chunk=q_chunk, use_rope=False)
+            with node_scope(sub, "xattn_out") as cfg:
+                y = L.attention_out(lp["xattn"], a, cfg)
+            with node_scope(sub, "add_x") as cfg:
+                h = constrain(h + y, cfg, BSD)
 
-        hn = L.apply_norm(lp["ln2"], h)
-        hn = constrain(hn, sub["ln2"], ("batch", "seq", "d_model"))
+        with node_scope(sub, "ln2") as cfg:
+            hn = constrain(L.apply_norm(lp["ln2"], h), cfg, BSD)
         if spec.mixer == "rwkv":
-            y, st = Rc.rwkv_cmix(lp["cmix"], hn, arch, sub["cmix"],
-                                 state=(lc or {}).get("cmix_state"),
-                                 q_lens=q_lens)
+            with node_scope(sub, "cmix") as cfg:
+                y, st = Rc.rwkv_cmix(lp["cmix"], hn, arch, cfg,
+                                     state=(lc or {}).get("cmix_state"),
+                                     q_lens=q_lens)
             if cache is not None:
                 nc["cmix_state"] = st
         elif spec.ffn == "moe":
-            if cache is None:
-                # hierarchical remat: one MoE layer's dispatch buffers live
-                # at a time during the unit's bwd recompute
-                y, a_loss = jax.checkpoint(
-                    lambda p_, h_: M.moe_ffn(p_, h_, arch, sub["moe"]),
-                    policy=jax.checkpoint_policies.nothing_saveable,
-                )(lp["moe"], hn)
-            else:
-                y, a_loss = M.moe_ffn(lp["moe"], hn, arch, sub["moe"])
+            with node_scope(sub, "moe") as cfg:
+                if cache is None:
+                    # hierarchical remat: one MoE layer's dispatch buffers
+                    # live at a time during the unit's bwd recompute
+                    y, a_loss = jax.checkpoint(
+                        lambda p_, h_: M.moe_ffn(p_, h_, arch, cfg),
+                        policy=jax.checkpoint_policies.nothing_saveable,
+                    )(lp["moe"], hn)
+                else:
+                    y, a_loss = M.moe_ffn(lp["moe"], hn, arch, cfg)
             aux = aux + a_loss
         else:
-            y = L.mlp(lp["mlp"], hn, sub["mlp_in"], sub["mlp_out"])
-        h = h + y
-        h = constrain(h, sub["add2"], ("batch", "seq", "d_model"))
+            y = L.mlp(lp["mlp"], hn, sub)
+        with node_scope(sub, "add2") as cfg:
+            h = constrain(h + y, cfg, BSD)
         new_cache[f"l{j}"] = nc
     return h, aux, new_cache
 
@@ -199,11 +211,16 @@ REMAT_POLICIES = {
 }
 
 
+@jax.named_scope("stack")
 def run_stack(h, stack_params, arch: ArchConfig, segments, *, positions,
               causal=True, cache=None, cache_pos=None, block_tables=None,
               q_lens=None, memory=None, q_chunk=512, time_chunk=64,
               remat=True, remat_policy="nothing"):
-    """Scan the unit stack segment by segment; returns (h, aux, new_cache)."""
+    """Scan the unit stack segment by segment; returns (h, aux, new_cache).
+
+    All of it runs under the ``stack`` scope, so the scan's slicing of
+    stacked weights and saved activations lands there, outside every
+    unit's sublayer scopes."""
     aux_total = jnp.zeros((), jnp.float32)
     new_cache_parts = []
 
@@ -248,6 +265,24 @@ def run_stack(h, stack_params, arch: ArchConfig, segments, *, positions,
 
 
 # --------------------------------------------------------------------------- #
+# the graph nodes outside the unit (shared by train / prefill / decode)
+# --------------------------------------------------------------------------- #
+def embed_tokens(params, tokens: jax.Array, plan: ModelPlan) -> jax.Array:
+    with node_scope(plan, "embed") as cfg:
+        return L.embed(params["embed"], tokens, cfg)
+
+
+def final_norm(params, h: jax.Array, plan: ModelPlan) -> jax.Array:
+    with node_scope(plan, "final_norm") as cfg:
+        return constrain(L.apply_norm(params["final_norm"], h), cfg, BSD)
+
+
+def head(params, h: jax.Array, arch: ArchConfig, plan: ModelPlan):
+    with node_scope(plan, "lm_head") as cfg:
+        return L.lm_head(params["lm_head"], h, params["embed"], arch, cfg)
+
+
+# --------------------------------------------------------------------------- #
 # full forward / loss
 # --------------------------------------------------------------------------- #
 def hidden_states(params, batch: dict, arch: ArchConfig,
@@ -255,7 +290,7 @@ def hidden_states(params, batch: dict, arch: ArchConfig,
                   remat=True, remat_policy="nothing"):
     """Embed + layer stack + final norm -> ((B, S, D), aux_loss)."""
     tokens = batch["tokens"]
-    h = L.embed(params["embed"], tokens, plan.embed)
+    h = embed_tokens(params, tokens, plan)
     if arch.frontend and "frontend" in batch:
         h = jnp.concatenate([batch["frontend"].astype(h.dtype), h], axis=1)
     S = h.shape[1]
@@ -264,9 +299,7 @@ def hidden_states(params, batch: dict, arch: ArchConfig,
                           positions=positions, causal=True, q_chunk=q_chunk,
                           time_chunk=time_chunk, remat=remat,
                           remat_policy=remat_policy)
-    h = L.apply_norm(params["final_norm"], h)
-    h = constrain(h, plan.final_norm, ("batch", "seq", "d_model"))
-    return h, aux
+    return final_norm(params, h, plan), aux
 
 
 def forward(params, batch: dict, arch: ArchConfig, plan: ModelPlan | None = None,
@@ -281,9 +314,7 @@ def forward(params, batch: dict, arch: ArchConfig, plan: ModelPlan | None = None
     h, aux = hidden_states(params, batch, arch, plan, q_chunk=q_chunk,
                            time_chunk=time_chunk, remat=remat,
                            remat_policy=remat_policy)
-    logits = L.lm_head(params["lm_head"], h, params["embed"], arch,
-                       plan.lm_head)
-    return logits, aux
+    return head(params, h, arch, plan), aux
 
 
 def softmax_xent(logits: jax.Array, labels: jax.Array,
@@ -307,31 +338,34 @@ def chunked_lm_loss(h: jax.Array, labels: jax.Array, params, arch: ArchConfig,
     (B, S, V) tensor — at 1M-token global batches that tensor is hundreds
     of TB and must not exist.
 
-    h: (B, T, D) hidden states; labels: (B, T) next-token targets.
+    h: (B, T, D) hidden states; labels: (B, T) next-token targets.  The
+    head and the cross entropy are one graph node, ``lm_head``.
     """
     B, T, D = h.shape
-    w = (params["embed"]["table"].T if arch.tie_embeddings
-         else params["lm_head"]["w"])
     n = T // chunk if (T % chunk == 0 and T > chunk) else 1
     c = T // n
-    hs = jnp.moveaxis(h.reshape(B, n, c, D), 1, 0)
-    ls = jnp.moveaxis(labels.reshape(B, n, c), 1, 0)
 
-    @jax.checkpoint
-    def body(carry, xs):
-        hc, lc = xs
-        logits = jnp.einsum("bcd,dv->bcv", hc, w)
-        logits = constrain(logits, plan.lm_head, ("batch", "seq", "vocab"))
-        lf = logits.astype(jnp.float32)
-        lse = jax.nn.logsumexp(lf, axis=-1)
-        gold = jnp.take_along_axis(lf, lc[..., None], axis=-1)[..., 0]
-        hit = (jnp.argmax(lf, axis=-1) == lc).astype(jnp.float32)
-        nll, z, acc = carry
-        return (nll + jnp.sum(lse - gold), z + jnp.sum(jnp.square(lse)),
-                acc + jnp.sum(hit)), None
+    with node_scope(plan, "lm_head") as cfg:
+        w = (params["embed"]["table"].T if arch.tie_embeddings
+             else params["lm_head"]["w"])
+        hs = jnp.moveaxis(h.reshape(B, n, c, D), 1, 0)
+        ls = jnp.moveaxis(labels.reshape(B, n, c), 1, 0)
 
-    zero = jnp.zeros((), jnp.float32)
-    (nll, z, acc), _ = jax.lax.scan(body, (zero, zero, zero), (hs, ls))
+        @jax.checkpoint
+        def body(carry, xs):
+            hc, lc = xs
+            logits = jnp.einsum("bcd,dv->bcv", hc, w)
+            logits = constrain(logits, cfg, ("batch", "seq", "vocab"))
+            lf = logits.astype(jnp.float32)
+            lse = jax.nn.logsumexp(lf, axis=-1)
+            gold = jnp.take_along_axis(lf, lc[..., None], axis=-1)[..., 0]
+            hit = (jnp.argmax(lf, axis=-1) == lc).astype(jnp.float32)
+            nll, z, acc = carry
+            return (nll + jnp.sum(lse - gold), z + jnp.sum(jnp.square(lse)),
+                    acc + jnp.sum(hit)), None
+
+        zero = jnp.zeros((), jnp.float32)
+        (nll, z, acc), _ = jax.lax.scan(body, (zero, zero, zero), (hs, ls))
     count = B * T
     loss = nll / count + z_loss_coef * z / count
     return loss, {"nll": nll / count, "accuracy": acc / count}
@@ -435,7 +469,7 @@ def prefill(params, batch: dict, cache: dict, arch: ArchConfig,
     """Process the prompt, filling ``cache``; returns (last_logits, cache)."""
     plan = plan if plan is not None else uniform_plan(arch)
     tokens = batch["tokens"]
-    h = L.embed(params["embed"], tokens, plan.embed)
+    h = embed_tokens(params, tokens, plan)
     if arch.frontend and "frontend" in batch:
         h = jnp.concatenate([batch["frontend"].astype(h.dtype), h], axis=1)
     S = h.shape[1]
@@ -444,11 +478,8 @@ def prefill(params, batch: dict, cache: dict, arch: ArchConfig,
                             positions=positions, causal=True, cache=cache,
                             cache_pos=0, q_chunk=q_chunk,
                             time_chunk=time_chunk, remat=False)
-    h = L.apply_norm(params["final_norm"], h[:, -1:, :])
-    h = constrain(h, plan.final_norm, ("batch", "seq", "d_model"))
-    logits = L.lm_head(params["lm_head"], h, params["embed"], arch,
-                       plan.lm_head)
-    return logits, cache
+    h = final_norm(params, h[:, -1:, :], plan)
+    return head(params, h, arch, plan), cache
 
 
 def decode_positions(pos, batch: int):
@@ -485,7 +516,7 @@ def decode_step(params, token: jax.Array, cache: dict, pos,
     paged block pool from :func:`init_paged_cache`; requires (B,)
     per-slot positions."""
     plan = plan if plan is not None else uniform_plan(arch)
-    h = L.embed(params["embed"], token, plan.embed)
+    h = embed_tokens(params, token, plan)
     positions, cache_pos = decode_positions(pos, token.shape[0])
     if block_tables is not None and cache_pos.ndim != 1:
         raise ValueError("paged decode (block_tables) requires a (B,) "
@@ -494,11 +525,7 @@ def decode_step(params, token: jax.Array, cache: dict, pos,
                             positions=positions, causal=True, cache=cache,
                             cache_pos=cache_pos, block_tables=block_tables,
                             remat=False)
-    h = L.apply_norm(params["final_norm"], h)
-    h = constrain(h, plan.final_norm, ("batch", "seq", "d_model"))
-    logits = L.lm_head(params["lm_head"], h, params["embed"], arch,
-                       plan.lm_head)
-    return logits, cache
+    return head(params, final_norm(params, h, plan), arch, plan), cache
 
 
 def step(params, tokens: jax.Array, cache: dict, pos, arch: ArchConfig,
@@ -538,15 +565,11 @@ def step(params, tokens: jax.Array, cache: dict, pos, arch: ArchConfig,
                 f"batch; got shape {q_lens.shape}")
     elif T > 1:
         q_lens = jnp.full((B,), T, jnp.int32)
-    h = L.embed(params["embed"], tokens, plan.embed)
+    h = embed_tokens(params, tokens, plan)
     positions = pos[:, None] + jnp.arange(T)[None, :]
     h, _, cache = run_stack(h, params["stack"], arch, plan.segments,
                             positions=positions, causal=True, cache=cache,
                             cache_pos=pos, block_tables=block_tables,
                             q_lens=q_lens, q_chunk=q_chunk,
                             time_chunk=time_chunk, remat=False)
-    h = L.apply_norm(params["final_norm"], h)
-    h = constrain(h, plan.final_norm, ("batch", "seq", "d_model"))
-    logits = L.lm_head(params["lm_head"], h, params["embed"], arch,
-                       plan.lm_head)
-    return logits, cache
+    return head(params, final_norm(params, h, plan), arch, plan), cache

@@ -14,7 +14,10 @@ train/prefill/decode phase is :class:`repro.plans.ParallelPlan`.
 
 from __future__ import annotations
 
+from contextlib import contextmanager
 from dataclasses import dataclass, field
+
+import jax
 
 from repro.core.config import LayerConfig
 from repro.core.graph import Strategy
@@ -44,6 +47,19 @@ def sublayer_keys(spec) -> list[str]:
 
 
 UnitPlan = tuple[dict[str, LayerConfig], ...]   # one dict per pattern layer
+
+
+@contextmanager
+def node_scope(plan, key: str):
+    """Yield the config ``plan`` gives graph node ``key``, naming every op
+    traced meanwhile after the node (``jax.named_scope(key)``), so that a
+    device trace attributes each op to the layer the search priced.
+    ``plan`` is one pattern layer's sublayer dict (keys of
+    :func:`sublayer_keys`) or a :class:`ModelPlan` (``embed``,
+    ``final_norm``, ``lm_head``)."""
+    cfg = plan[key] if isinstance(plan, dict) else getattr(plan, key)
+    with jax.named_scope(key):
+        yield cfg
 
 
 @dataclass(frozen=True)

@@ -57,6 +57,7 @@ def global_norm(tree) -> jax.Array:
     return jnp.sqrt(jnp.sum(jnp.stack(leaves)))
 
 
+@jax.named_scope("adamw")
 def adamw_update(params, grads, state, cfg: AdamWConfig):
     """Returns (new_params, new_state, metrics)."""
     step = state["step"] + 1

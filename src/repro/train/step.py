@@ -31,6 +31,9 @@ from repro.models.plan import ModelPlan, Segment
 from repro.optim import AdamWConfig, adamw_update
 from repro.plans.parallel_plan import ParallelPlan, as_model_plan
 
+# the scope of the microbatch gradient sum, beside the model's graph nodes
+ACCUMULATE = "accumulate"
+
 
 @dataclass(frozen=True)
 class TrainConfig:
@@ -95,18 +98,21 @@ def make_train_step(arch: ArchConfig,
 
             def body(acc_g, mb_i):
                 (l, met), g = grad_fn(params, mb_i)
-                acc_g = jax.tree.map(
-                    lambda a, x: a + x.astype(jnp.float32), acc_g, g)
+                with jax.named_scope(ACCUMULATE):
+                    acc_g = jax.tree.map(
+                        lambda a, x: a + x.astype(jnp.float32), acc_g, g)
                 return acc_g, met
 
             # derive the f32 accumulator FROM params so the (FSDP) param
             # sharding propagates to it — a fresh jnp.zeros has no sharding
             # link and XLA replicates it, all-reducing full-size grads per
             # microbatch (observed: 2.9 TB/dev/step on olmoe, see §Perf).
-            zeros = jax.tree.map(
-                lambda x: (x * 0).astype(jnp.float32), params)
+            with jax.named_scope(ACCUMULATE):
+                zeros = jax.tree.map(
+                    lambda x: (x * 0).astype(jnp.float32), params)
             grads, mets = jax.lax.scan(body, zeros, mb)
-            grads = jax.tree.map(lambda g: g / m, grads)
+            with jax.named_scope(ACCUMULATE):
+                grads = jax.tree.map(lambda g: g / m, grads)
             metrics = jax.tree.map(lambda v: jnp.mean(v, axis=0), mets)
 
         new_params, new_state, om = adamw_update(
@@ -154,9 +160,6 @@ def _make_staged_train_step(arch: ArchConfig, plan: ModelPlan,
         raise ValueError(
             f"stage assignment covers {stages.n_units} units but "
             f"{arch.name} has {arch.n_units}")
-    from repro.core.sharding import constrain
-    from repro.models import layers as L
-
     S = stages.num_stages
     M = max(1, stages.microbatches)
     seg_lists = [_stage_segments(plan.segments, *stages.unit_range(s))
@@ -168,7 +171,7 @@ def _make_staged_train_step(arch: ArchConfig, plan: ModelPlan,
 
     def fwd_first(p, mb):
         tokens = mb["tokens"]
-        h = L.embed(p["embed"], tokens, plan.embed)
+        h = mod.embed_tokens(p, tokens, plan)
         if arch.frontend and "frontend" in mb:
             h = jnp.concatenate([mb["frontend"].astype(h.dtype), h], axis=1)
         h, aux, _ = mod.run_stack(h, p["stack"], arch, seg_lists[0],
@@ -190,8 +193,7 @@ def _make_staged_train_step(arch: ArchConfig, plan: ModelPlan,
         h, aux, _ = mod.run_stack(h, p["stack"], arch, seg_lists[S - 1],
                                   positions=jnp.arange(h.shape[1]),
                                   causal=True, **stack_kw)
-        h = L.apply_norm(p["final_norm"], h)
-        h = constrain(h, plan.final_norm, ("batch", "seq", "d_model"))
+        h = mod.final_norm(p, h, plan)
         h_text = h[:, -tokens.shape[1]:, :]
         lm_loss, met = mod.chunked_lm_loss(h_text[:, :-1, :], tokens[:, 1:],
                                            p, arch, plan,
@@ -242,6 +244,7 @@ def _make_staged_train_step(arch: ArchConfig, plan: ModelPlan,
         _acc_stage(acc, g0, stages, 0)
         return acc
 
+    @jax.named_scope(ACCUMULATE)
     def _acc_stage(acc, g, st, s):
         b0, b1 = st.unit_range(s)
         acc["stack"] = jax.tree.map(
@@ -262,7 +265,8 @@ def _make_staged_train_step(arch: ArchConfig, plan: ModelPlan,
         sp = [stage_params(params, s) for s in range(S)]
         # f32 accumulator derived FROM params so the param sharding
         # propagates (see the unstaged path's note)
-        acc = jax.tree.map(lambda x: (x * 0).astype(jnp.float32), params)
+        with jax.named_scope(ACCUMULATE):
+            acc = jax.tree.map(lambda x: (x * 0).astype(jnp.float32), params)
 
         def mb_i(i):
             return jax.tree.map(lambda x: x[i], mbs)
@@ -283,7 +287,8 @@ def _make_staged_train_step(arch: ArchConfig, plan: ModelPlan,
                 mets.append(met)
                 nxt += 1
 
-        grads = jax.tree.map(lambda g: g / M, acc)
+        with jax.named_scope(ACCUMULATE):
+            grads = jax.tree.map(lambda g: g / M, acc)
         metrics = {k: jnp.mean(jnp.stack([m[k] for m in mets]))
                    for k in mets[0]}
         new_params, new_state, om = adamw_update(
