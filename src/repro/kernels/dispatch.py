@@ -240,9 +240,16 @@ _SPMD_SKIPS: Counter = Counter()       # (op, backend) -> passed over
 def call(op: str, *args, backend: str | None = None, **kwargs):
     """Dispatch ``op`` to the selected backend implementation."""
     impl = select(op, *args, backend=backend, **kwargs)
-    with _COUNT_LOCK:
-        _SELECTIONS[(op, impl.backend)] += 1
+    record_selection(op, impl.backend)
     return impl.fn(*args, **kwargs)
+
+
+def record_selection(op: str, backend: str) -> None:
+    """Count a resolution made outside :func:`call` (a kernel's backward
+    choosing its own path at trace time), so that
+    :func:`selection_counts` shows it beside the dispatched ones."""
+    with _COUNT_LOCK:
+        _SELECTIONS[(op, backend)] += 1
 
 
 def selection_counts() -> dict[tuple[str, str], int]:

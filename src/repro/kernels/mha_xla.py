@@ -7,7 +7,8 @@ vectors — so it backs three roles:
 
 * the ``flash_attention`` dispatch backend wherever Pallas cannot run (or
   the reference path would materialize too large a score tensor);
-* the backward pass of the fwd-only Pallas kernels (reference VJP);
+* the backward pass of the Pallas flash kernel for shapes its own
+  backward kernels cannot block (reference VJP);
 * the ``kv_override`` / cross-attention path in ``repro.models.layers``
   (which needs free-form positions the blocked kernels do not take).
 

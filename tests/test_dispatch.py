@@ -268,6 +268,34 @@ def test_interpret_backend_is_differentiable():
                                atol=1e-4, rtol=1e-4)
 
 
+@pytest.mark.parametrize("S,path", [
+    (256, "pallas"),       # lane-aligned: the Pallas backward
+    (192, "reference"),    # the forward takes it; no 128-multiple block
+])
+def test_flash_backward_path_is_counted(S, path):
+    """Each backward resolution of the kernel op is on record, and either
+    path gives the reference's gradients."""
+    q, k, v = _flash_args(S=S)
+
+    def loss(q, k, v):
+        return dispatch.call("flash_attention", q, k, v, causal=True,
+                             block_q=64, block_k=64,
+                             backend="interpret").sum()
+
+    dispatch.reset_selection_counts()
+    got = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    counts = dispatch.selection_counts()
+    assert counts.get(("flash_attention_bwd", path), 0) >= 1
+    assert [p for (op, p) in counts if op == "flash_attention_bwd"] == [path]
+    want = jax.grad(lambda q, k, v: ref.attention_ref(q, k, v,
+                                                      causal=True).sum(),
+                    argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   atol=1e-4, rtol=1e-4)
+    dispatch.reset_selection_counts()
+
+
 # --------------------------------------------------------------------------- #
 # autotune cache
 # --------------------------------------------------------------------------- #

@@ -40,6 +40,36 @@ def test_flash_attention(B, H, KH, S, D, dtype, causal):
         atol=TOL[dtype], rtol=TOL[dtype])
 
 
+@pytest.mark.parametrize("B,H,KH,S,D,block_q,block_k", [
+    (1, 4, 4, 256, 64, 128, 128),     # MHA
+    (2, 8, 2, 256, 64, 128, 256),     # GQA 4:1, block_q != block_k
+    (1, 4, 1, 512, 64, 256, 128),     # MQA
+    (1, 2, 2, 256, 128, 256, 256),    # one block each way, bigger head
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_attention_backward(B, H, KH, S, D, block_q, block_k, dtype,
+                                  causal):
+    """dq, dk, dv of the Pallas backward against the reference's VJP."""
+    from repro.kernels.flash_attention import flash_attention_bwd
+    ks = jax.random.split(jax.random.PRNGKey(1), 4)
+    q = _rand(ks[0], (B, H, S, D), dtype)
+    k = _rand(ks[1], (B, KH, S, D), dtype)
+    v = _rand(ks[2], (B, KH, S, D), dtype)
+    do = _rand(ks[3], (B, H, S, D), dtype)
+    o, vjp = jax.vjp(lambda q, k, v: ref.attention_ref(q, k, v,
+                                                       causal=causal),
+                     q, k, v)
+    got = flash_attention_bwd(q, k, v, o, do, causal=causal,
+                              block_q=block_q, block_k=block_k,
+                              interpret=True)
+    for g, want in zip(got, vjp(do)):
+        assert g.dtype == want.dtype and g.shape == want.shape
+        np.testing.assert_allclose(
+            np.asarray(g, np.float32), np.asarray(want, np.float32),
+            atol=5 * TOL[dtype], rtol=5 * TOL[dtype])
+
+
 @pytest.mark.parametrize("B,KH,G,T,D", [
     (1, 2, 4, 512, 64),
     (2, 4, 8, 1024, 128),
