@@ -1,38 +1,31 @@
 """Operations and bytes from logical shapes, for MFU and roofline shares.
 
-Counted by hand from the dense decoder's equations (copied in spirit from
-the repo's ``benchmarks/roofline.py::model_flops``: 6·N per trained
-token plus causal attention, which 6·N leaves out).  Recomputation is
-never counted: these are the operations the model needs, not the ones a
+The block's own counts come from the configuration's family
+(``bench/reference/<family>.py``: the parameters that multiply every
+token, the attention layers' shape and their score and sum operations,
+counted by hand from the block's equations); the sums here are the same
+for every block (copied in spirit from the repo's
+``benchmarks/roofline.py::model_flops``: 6·N per trained token plus
+causal attention, which 6·N leaves out).  Recomputation is never
+counted: these are the operations the model needs, not the ones a
 schedule happens to run.
 """
 
 from __future__ import annotations
 
+from bench.reference import family
+
 
 def matmul_params(config: dict) -> int:
-    """Parameters that multiply every token: the layers' projections and
-    the output head (the embedding lookup multiplies nothing)."""
-    d, h, kh = (config["hidden_size"], config["num_attention_heads"],
-                config["num_key_value_heads"])
-    hd, f, v = config["head_dim"], config["intermediate_size"], config["vocab_size"]
-    attn = d * (h + 2 * kh) * hd + h * hd * d
-    mlp = 3 * d * f
-    return config["num_hidden_layers"] * (attn + mlp) + d * v
+    """Parameters that multiply every token (the family's count)."""
+    return family(config).matmul_params(config)
 
 
 def attention_flops(config: dict, q_tokens: int, kv_len: int,
                     causal: bool) -> float:
-    """Forward scores and weighted sum of one sequence: ``q_tokens``
-    queries against ``kv_len`` keys (causal: the triangle only, each
-    query at the end of the keys)."""
-    h, hd, n = (config["num_attention_heads"], config["head_dim"],
-                config["num_hidden_layers"])
-    if causal:
-        pairs = q_tokens * kv_len - q_tokens * (q_tokens - 1) / 2
-    else:
-        pairs = q_tokens * kv_len
-    return 4.0 * h * hd * pairs * n
+    """Forward attention operations of one sequence over every attention
+    layer (the family's count)."""
+    return family(config).attention_flops(config, q_tokens, kv_len, causal)
 
 
 def train_flops_per_token(config: dict, seq: int) -> float:
@@ -62,11 +55,10 @@ def flash_attention_cost(batch: int, heads: int, kv_heads: int, seq: int,
 
 def paged_decode_cost(config: dict, kv_lens, itemsize: int) -> tuple[float, float]:
     """(operations, bytes) of one single-token paged-decode attention
-    pass over every layer: each live slot reads its ``kv_len`` keys and
-    values at the pool's width and its query, and writes its output."""
-    h, kh, hd, n = (config["num_attention_heads"],
-                    config["num_key_value_heads"], config["head_dim"],
-                    config["num_hidden_layers"])
+    pass over every attention layer: each live slot reads its ``kv_len``
+    keys and values at the pool's width and its query, and writes its
+    output."""
+    n, h, kh, hd = family(config).attention_shape(config)
     total = float(sum(kv_lens))
     flops = 4.0 * h * hd * total * n
     nbytes = n * (2 * kh * hd * total + 2 * h * hd * len(kv_lens)) * itemsize

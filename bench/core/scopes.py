@@ -313,28 +313,22 @@ def node_scope(name: str) -> str:
 
 
 def cell_pricing(cell):
-    """(graph, cost model, config_of) for a one-chip training cell: the
-    cell's graph, the program's cost model for the chip in use, and
+    """(graph, cost model, config_of) for a training cell: the cell's
+    graph, the program's cost model for the cell's host mesh
+    (``launch.mesh.host_mesh``, priced for the chip in use), and
     ``config_of(name, node)``, the config each node gets from the plan
-    that ``bench/drivers/train.py`` resolves.  ``None`` for a cell on
-    several chips, whose mesh the cost model would have to be given."""
-    if cell.chips != 1:
-        return None
-    import jax
-
+    that ``bench/drivers/train.py`` resolves on that mesh."""
     from repro.core.cost_model import CostModel
-    from repro.core.device import ICI_BW, AxisSpec, MeshSpec, chip_for
+    from repro.launch.mesh import host_mesh
     from repro.plans import resolve_plan
 
-    quiet = lambda *a, **k: None
     tr = cell.traffic
     arch, graph = cell_graph(cell)
-    dev = jax.devices()[0]
-    mesh = MeshSpec(axes=(AxisSpec("data", 1, ICI_BW),),
-                    chip=chip_for(dev.platform, dev.device_kind, log=quiet))
-    plan = resolve_plan(arch, None, phases=("train",),
-                        strategy=tr["strategy"], train_seq=tr["seq"],
-                        train_batch=tr["batch"], log=quiet).plan_for("train")
+    _, mesh = host_mesh(cell.chips)
+    plan = resolve_plan(arch, mesh if cell.chips > 1 else None,
+                        phases=("train",), strategy=tr["strategy"],
+                        train_seq=tr["seq"], train_batch=tr["batch"],
+                        log=lambda *a, **k: None).plan_for("train")
 
     def config_of(name, node):
         if "." not in name:                   # embed, final_norm, lm_head

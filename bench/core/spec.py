@@ -3,7 +3,8 @@
 A cell names a configuration (``bench/configs/<config>.json``) and a
 traffic mix (``bench/traffic/<traffic>.json``).  The configuration holds
 the model's numbers as run; ``program_arch`` builds the program's own
-``ArchConfig`` from them and refuses one whose shape disagrees.
+``ArchConfig`` from them and has the configuration's family refuse one
+whose shape disagrees.
 """
 
 from __future__ import annotations
@@ -12,6 +13,8 @@ import dataclasses
 import json
 from dataclasses import dataclass
 from pathlib import Path
+
+from bench.reference import family
 
 BENCH = Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
@@ -51,37 +54,17 @@ def load_traffic(name: str, root: Path = ROOT) -> dict:
     return json.loads((root / "bench" / "traffic" / f"{name}.json").read_text())
 
 
-# the configuration's key -> the program's ArchConfig field
-_ARCH_FIELDS = {
-    "num_hidden_layers": "n_layers", "hidden_size": "d_model",
-    "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
-    "head_dim": "hd", "intermediate_size": "d_ff", "vocab_size": "vocab",
-    "rope_theta": "rope_theta", "tie_word_embeddings": "tie_embeddings",
-}
-
-
 def program_arch(config: dict):
     """The program's ``ArchConfig`` for this configuration: the arch the
     program registers under ``program_arch`` (its small CPU preset where
     ``program_preset`` is ``"reduced"``, as the tests run it), cut to the
-    configuration's depth.  Every other number has to agree already."""
+    configuration's depth.  The configuration's family refuses it where
+    any other number or the block disagrees (``check_arch``)."""
     from repro import configs
-    from repro.models.arch import LayerSpec
 
     get = (configs.reduced if config.get("program_preset") == "reduced"
            else configs.get)
     arch = get(config["program_arch"])
     arch = dataclasses.replace(arch, n_layers=config["num_hidden_layers"])
-    wrong = {k: (config[k], getattr(arch, f))
-             for k, f in _ARCH_FIELDS.items() if config[k] != getattr(arch, f)}
-    if arch.nonparam_norm == config["norm_with_affine"]:
-        wrong["norm_with_affine"] = (config["norm_with_affine"],
-                                     not arch.nonparam_norm)
-    if (set(arch.pattern) != {LayerSpec("attn", "dense")} or arch.qkv_bias
-            or arch.qk_norm or arch.n_experts or arch.frontend):
-        wrong["block"] = ("dense decoder", arch.pattern)
-    if wrong:
-        raise ValueError(f"{config['name']}: the program's arch "
-                         f"{config['program_arch']!r} differs from the "
-                         f"configuration (config, program): {wrong}")
+    family(config).check_arch(config, arch)
     return arch

@@ -1,62 +1,28 @@
 """Weights and training batches made on the device from ``--seed``.
 
-The tree has the program's layout for a dense decoder (checked against
-``jax.eval_shape`` of the program's ``init_lm``): per-layer leaves are
-stacked over the layers, norms hold an offset ``scale`` (weight = 1 +
-scale) or nothing when the configuration's norm has no affine part, and
-a tied head holds no leaf of its own.  Each leaf draws from its own key,
-so one leaf can be made again alone (``leaf_change_norms``).
+The tree has the layout the configuration's family gives
+(``leaf_specs``, ``empty_tree`` of ``bench/reference/<family>.py``),
+which is the program's (checked against ``jax.eval_shape`` of the
+program's ``init_lm``).  Each leaf draws from its own key, so one leaf
+can be made again alone (``leaf_change_norms``).
 """
 
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 
+from bench.reference import family
+
 WEIGHTS, BATCHES = 0, 1           # salts: weights and data never share keys
-EMBED_STD = 0.02                  # the program's own embedding scale
-NORM_STD = 0.1                    # norm offsets: nonzero, so a lost one shows
 
 
 def seed_key(seed: int, salt: int) -> jax.Array:
     """A key for any whole-number seed (more than 32 bits included)."""
     key = jax.random.fold_in(jax.random.key(seed % 2**32), seed // 2**32)
     return jax.random.fold_in(key, salt)
-
-
-def leaf_specs(config: dict) -> list[tuple[tuple[str, ...], tuple, float]]:
-    """(path, shape, std) of every leaf, in a fixed order."""
-    n, d = config["num_hidden_layers"], config["hidden_size"]
-    h, kh = config["num_attention_heads"], config["num_key_value_heads"]
-    hd, f, v = config["head_dim"], config["intermediate_size"], config["vocab_size"]
-    specs = [(("embed", "table"), (v, d), EMBED_STD)]
-    layer = [
-        (("attn", "wq"), (n, d, h, hd), 1 / math.sqrt(d)),
-        (("attn", "wk"), (n, d, kh, hd), 1 / math.sqrt(d)),
-        (("attn", "wv"), (n, d, kh, hd), 1 / math.sqrt(d)),
-        (("attn", "wo"), (n, h, hd, d), 1 / math.sqrt(h * hd)),
-        (("mlp", "wi"), (n, d, f), 1 / math.sqrt(d)),
-        (("mlp", "wg"), (n, d, f), 1 / math.sqrt(d)),
-        (("mlp", "wo"), (n, f, d), 1 / math.sqrt(f)),
-    ]
-    if config["norm_with_affine"]:
-        layer += [(("ln1", "scale"), (n, d), NORM_STD),
-                  (("ln2", "scale"), (n, d), NORM_STD)]
-    specs += [(("stack", "l0") + p, s, std) for p, s, std in layer]
-    if config["norm_with_affine"]:
-        specs.append((("final_norm", "scale"), (d,), NORM_STD))
-    if not config["tie_word_embeddings"]:
-        specs.append((("lm_head", "w"), (d, v), 1 / math.sqrt(d)))
-    return specs
-
-
-def _empty_tree() -> dict:
-    return {"embed": {}, "stack": {"l0": {"ln1": {}, "ln2": {}, "attn": {},
-                                          "mlp": {}}},
-            "final_norm": {}, "lm_head": {}}
 
 
 def _set(tree: dict, path: tuple[str, ...], leaf) -> None:
@@ -72,11 +38,12 @@ def _leaf(key, i: int, shape, std, dtype):
 
 def make_params(config: dict, seed: int, dtype=jnp.float32) -> dict:
     """Every weight, in one jitted call on the default device."""
-    specs = leaf_specs(config)
+    fam = family(config)
+    specs = fam.leaf_specs(config)
 
     @jax.jit
     def build(key):
-        tree = _empty_tree()
+        tree = fam.empty_tree()
         for i, (path, shape, std) in enumerate(specs):
             _set(tree, path, _leaf(key, i, shape, std, dtype))
         return tree
@@ -88,7 +55,7 @@ def leaf_change_norms(config: dict, seed: int, params: dict) -> dict:
     """Per leaf, the norm of ``params - (the weights made from seed)``;
     each initial leaf is made again inside the reduction, so no copy of
     the initial weights is kept."""
-    specs = leaf_specs(config)
+    specs = family(config).leaf_specs(config)
 
     @jax.jit
     def norms(key, params):
@@ -107,8 +74,8 @@ def leaf_change_norms(config: dict, seed: int, params: dict) -> dict:
 
 
 def leaf_norms(tree: dict, scale: float = 1.0) -> dict:
-    """Per leaf, the norm of ``tree`` times ``scale``, keyed like
-    ``leaf_specs`` paths."""
+    """Per leaf, the norm of ``tree`` times ``scale``, keyed like the
+    family's ``leaf_specs`` paths."""
     flat = jax.tree_util.tree_flatten_with_path(tree)[0]
     fn = jax.jit(lambda leaves: [jnp.sqrt(jnp.sum(jnp.square(
         x.astype(jnp.float32)))) for x in leaves])

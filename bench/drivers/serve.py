@@ -7,7 +7,8 @@ and runs a ramp of the mix's own traffic so the window opens on a filled
 server.  In the window the harness submits each request when it is due
 (``submit``) and drives ``step``; a request's tokens are stamped when
 the step that made them returns.  After the window the engine is freed
-and the plain reference scores a sample of the finished requests.
+and the configuration family's reference scores a sample of the
+finished requests.
 """
 
 from __future__ import annotations
@@ -23,6 +24,7 @@ from bench.core import flops, weights
 from bench.core.runtime import (Profiler, SetupParts, memory_peak_bytes,
                                  span)
 from bench.core.spec import program_arch
+from bench.reference import family
 
 SPANS = ("engine.step", "engine.submit", "arrival pacing")
 FAULTS = ("token", "state")
@@ -243,8 +245,7 @@ def _check(cfg, tr, reqs, finished, opts, max_len):
     bfloat16) and for one altered token per request."""
     import jax.numpy as jnp
 
-    from bench.reference import dense
-
+    ref = family(cfg)
     by_uid = {r.uid: r for r in reqs}
     done = sorted(finished)
     if not done:
@@ -256,10 +257,10 @@ def _check(cfg, tr, reqs, finished, opts, max_len):
     sample = [longest] + sorted(rng.choice(rest, size=k, replace=False).tolist()
                                 if k else [])
     params = weights.make_params(cfg, opts.seed)
-    gap_fn = dense.served_gap_fn(cfg)
+    gap_fn = ref.served_gap_fn(cfg)
     if opts.control:
-        low = dense.int8_weights(params)
-        pick_fn = dense.control_pick_fn(cfg)
+        low = ref.int8_weights(params)
+        pick_fn = ref.control_pick_fn(cfg)
     gaps_all = []
     for uid in sample:
         prompt, served = list(by_uid[uid].prompt), finished[uid]

@@ -2,12 +2,12 @@
 
 Set-up makes the weights from the seed, resolves the plan
 (``resolve_plan``), builds the step (``make_train_step``), lays the
-parameters and AdamW state out (``param_pspecs``, ``to_shardings``) and
-jits the step with donation and pinned output shardings, as
-``launch/train.run`` does.  That one object then runs the first three
-steps, whose loss, first gradient and update the reference checks, and
-goes on into the window: fresh batches from the seed, every step waited
-on.
+parameters and AdamW state out (``param_pspecs``, ``to_shardings``,
+``adamw_shardings``) and jits the step with donation and pinned output
+shardings, as ``launch/train.run`` does.  That one object then runs the
+first three steps, whose loss, first gradient and update the
+configuration family's reference checks over the cell's chips, and goes
+on into the window: fresh batches from the seed, every step waited on.
 """
 
 from __future__ import annotations
@@ -19,6 +19,7 @@ from bench.core import checks, weights
 from bench.core.runtime import (Profiler, SetupParts, memory_peak_bytes,
                                  span)
 from bench.core.spec import program_arch
+from bench.reference import family
 
 SPANS = ("train.batch", "train.step", "train.fetch")
 FAULTS = ("state", "half")
@@ -27,7 +28,6 @@ CHECKED_STEPS = 3
 
 def run(cell, opts, t_start: float) -> dict:
     import jax
-    from jax.sharding import NamedSharding, PartitionSpec
 
     from repro.core.sharding import use_mesh
     from repro.kernels import dispatch
@@ -67,7 +67,7 @@ def run(cell, opts, t_start: float) -> dict:
     params = weights.make_params(cfg, opts.seed)
     weights.check_layout(params, arch)
     p_sh = to_shardings(param_pspecs(params, arch, plan), mesh, like=params)
-    o_sh = {"m": p_sh, "v": p_sh, "step": NamedSharding(mesh, PartitionSpec())}
+    o_sh = adamw_shardings(p_sh, mesh)
     params = jax.device_put(params, p_sh)
     opt = jax.device_put(adamw_init(params), o_sh)
     step = jax.jit(step_fn, donate_argnums=(0, 1),
@@ -148,7 +148,8 @@ def run(cell, opts, t_start: float) -> dict:
     # ---- correctness: the reference follows the first three steps ----- #
     del params, opt, met, batch
     gc.collect()
-    readings, control = _check(cfg, tr, opts, losses, first_grad, change)
+    readings, control = _check(cfg, tr, opts, jax.devices()[:n_dev], losses,
+                               first_grad, change)
     return {"attempted": steps, "failed": 0, "metrics": metrics,
             "readings": readings, "control": control, "record": record,
             "memory_peak_bytes": mem, "spans": SPANS}
@@ -164,14 +165,30 @@ def _readings(ref: dict, losses, first_grad, change) -> dict:
             "grad_norm_gap": grad_gap, "update_norm_gap": change_gap}
 
 
-def _check(cfg, tr, opts, losses, first_grad, change):
+def adamw_shardings(p_sh, mesh):
+    """The AdamW state's shardings for parameters laid out as ``p_sh``:
+    the program's own rule where it has one (``repro.plans.
+    adamw_shardings(param_shardings, mesh)``), so a change of the
+    program's optimizer layout reaches the benchmark; else the rule
+    ``launch/train.run`` writes out, moments as their parameters and the
+    step replicated."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro import plans
+
+    own = getattr(plans, "adamw_shardings", None)
+    if own is not None:
+        return own(p_sh, mesh)
+    return {"m": p_sh, "v": p_sh, "step": NamedSharding(mesh, PartitionSpec())}
+
+
+def _check(cfg, tr, opts, devices, losses, first_grad, change):
     """Run the reference over the same first batches from the same
-    weights, and compare; with ``opts.control``, read the control (the
-    reference in bfloat16) and the half-batch fault the same way."""
+    weights, with its state split over the cell's ``devices``, and
+    compare; with ``opts.control``, read the control (the reference in
+    bfloat16) and the half-batch fault the same way."""
     import jax
     import jax.numpy as jnp
-
-    from bench.reference import dense
 
     B, S, V = tr["batch"], tr["seq"], cfg["vocab_size"]
     batches = [weights.make_batch(opts.seed, i, B, S, V)["tokens"]
@@ -179,11 +196,13 @@ def _check(cfg, tr, opts, losses, first_grad, change):
     change_fn = lambda p: weights.leaf_change_norms(cfg, opts.seed, p)
 
     def follow(dtype=jnp.float32, precision="highest", row_filter=None):
-        params = jax.tree.map(lambda x: x.astype(dtype),
-                              weights.make_params(cfg, opts.seed))
-        return dense.train_readings(
-            params, batches, cfg, tr["optimizer"], cfg["z_loss_coef"],
-            change_fn, rows_per_block=tr["reference_rows"],
+        # made inline, so the tree made on one chip is dropped once the
+        # reference has split it over the cell's chips
+        return family(cfg).train_readings(
+            jax.tree.map(lambda x: x.astype(dtype),
+                         weights.make_params(cfg, opts.seed)),
+            batches, cfg, tr["optimizer"], cfg["z_loss_coef"], change_fn,
+            devices=devices, rows_per_block=tr["reference_rows"],
             precision=precision, row_filter=row_filter)
 
     ref = follow()

@@ -4,9 +4,9 @@ the sum over the scopes of the cell's train graph (``embed``, each
 sublayer key, ``final_norm``, ``lm_head``) of |predicted - measured|,
 over the sum measured.  Predicted: the sum over the scope's graph nodes
 of ``CostModel(phase="train").roofline_time`` under the plan that
-``bench/drivers/train.py`` resolves on one chip.  Measured: the scope's
-self time in every phase (profiler trace, averaged over chips) per
-traced step.  Moves train_tokens_per_s."""
+``bench/drivers/train.py`` resolves on the cell's mesh.  Measured: the
+scope's self time in every phase (profiler trace, averaged over chips)
+per traced step.  Moves train_tokens_per_s."""
 
 from bench.core import scopes
 
@@ -14,10 +14,9 @@ from bench.core import scopes
 def read(rec):
     got = scopes.rec_table(rec)
     steps = len(rec.get("traced_walls") or ())
-    pricing = scopes.cell_pricing(rec["cell"])
-    if got is None or not steps or pricing is None:
+    if got is None or not steps:
         return None
-    predicted = scopes.predicted_by_scope(*pricing)
+    predicted = scopes.predicted_by_scope(*scopes.cell_pricing(rec["cell"]))
     measured = {s: sum(got[0][s].values()) / steps
                 for s in predicted if s in got[0]}
     if not any(measured.values()):

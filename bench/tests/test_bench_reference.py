@@ -52,7 +52,7 @@ def test_train_readings_match_program(toy):
             weights.make_params(toy, seed), [b["tokens"] for b in batches],
             toy, OPT, toy["z_loss_coef"],
             lambda p: weights.leaf_change_norms(toy, seed, p),
-            rows_per_block=2)
+            devices=jax.devices()[:1], rows_per_block=2)
     np.testing.assert_allclose(losses, ref["loss"], rtol=1e-5)
     for k, v in ref["grad_norm"].items():
         np.testing.assert_allclose(grad[k], v, rtol=1e-3, err_msg=k)
