@@ -39,6 +39,19 @@ from .device import MeshSpec
 PARAM_DIMS = frozenset({"heads", "d_ff", "vocab", "expert", "d_model"})
 # Dimensions that partition *data* (activations only).
 DATA_DIMS = frozenset({"batch", "seq"})
+# Data-parallel mesh axes: ZeRO-1 splits the AdamW moments over those of
+# them that do not already split (or FSDP-store) the parameter.
+ZERO1_AXES = ("pod", "data")
+
+
+def zero1_axes(stored_axes: Iterable[str],
+               mesh_axes: Iterable[str]) -> tuple[str, ...]:
+    """The axes, beside ``stored_axes`` (those a parameter is stored
+    split over), that ZeRO-1 splits its AdamW moments over: the one rule
+    the memory count (``LayerConfig.moment_store_degree``) and the
+    program's layout (``repro.plans.adamw_shardings``) share."""
+    stored, present = set(stored_axes), set(mesh_axes)
+    return tuple(a for a in ZERO1_AXES if a in present and a not in stored)
 
 
 @dataclass(frozen=True, order=True)
@@ -118,6 +131,14 @@ class LayerConfig:
         if self.fsdp:
             deg *= mesh.degree(self.replicating_axes(mesh))
         return deg
+
+    def moment_store_degree(self, mesh: MeshSpec) -> int:
+        """Total ways the AdamW moments are split per device: the
+        parameters' split times ZeRO-1's (:func:`zero1_axes`)."""
+        stored = self.param_axes() + (
+            self.replicating_axes(mesh) if self.fsdp else ())
+        return self.param_store_degree(mesh) * mesh.degree(
+            zero1_axes(stored, mesh.axis_names))
 
     def is_valid(self, mesh: MeshSpec,
                  allowed_dims: Iterable[str] | None = None) -> bool:

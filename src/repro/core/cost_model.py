@@ -485,22 +485,19 @@ def pipeline_time(stage_costs, interstage_bytes: float, stage_bw: float,
 # --------------------------------------------------------------------------- #
 def node_device_bytes(node: LayerNode, cfg: LayerConfig, mesh: MeshSpec,
                       training: bool) -> float:
-    """Persistent per-device bytes this node pins: parameters (+grads +f32
-    moments under training, moments always ZeRO-1-sharded over the data
-    axes) and the KV cache for decode attention."""
-    pdeg = max(1, cfg.param_store_degree(mesh))
-    param = node.param_bytes / pdeg
+    """Persistent per-device bytes this node pins, as the program holds
+    them (``node.param_bytes`` is at the program's f32 width): the
+    parameters; under training also their gradients and the f32
+    microbatch accumulator (``train/step.py``; counted for every training
+    phase, since the search is not told the microbatch count), each split
+    as the parameters, and the two f32 AdamW moments, split further by
+    ZeRO-1 (``LayerConfig.moment_store_degree``); and the KV cache for
+    decode attention."""
+    param = node.param_bytes / max(1, cfg.param_store_degree(mesh))
     total = param
     if training:
-        total += param                              # grads (same sharding)
-        # f32 moments (2x the bf16 param bytes each), always ZeRO-1-sharded
-        # over the replicating data axes on top of the param sharding
-        zero1 = max(1, mesh.degree(tuple(
-            a for a in cfg.replicating_axes(mesh) if a in ("pod", "data"))))
-        base_deg = max(1, cfg.degree(
-            mesh, dims=[d for d in cfg.dims if d not in ("batch", "seq")]))
-        mom_deg = max(pdeg, base_deg * zero1)
-        total += 2 * (node.param_bytes * 2) / mom_deg   # m + v
+        total += 2 * param                          # grads + accumulator
+        total += 2 * node.param_bytes / cfg.moment_store_degree(mesh)
     if node.extra.get("decode") and node.kind in ("attn", "cross_attn"):
         kv = node.extra.get("kv_bytes", 0.0)
         kv_deg = max(1, cfg.degree(mesh, dims=("batch", "seq", "heads")))
@@ -508,10 +505,29 @@ def node_device_bytes(node: LayerNode, cfg: LayerConfig, mesh: MeshSpec,
     return total
 
 
+# Bytes a chip holds beyond the persistent count (node_device_bytes): the
+# step's activations and XLA's own temporaries.  Training's is read from
+# the compiled olmo-1b step (16 layers, 16 x 2048 in 4 microbatches,
+# remat) for a v5e:2x2 under its searched plan: arguments + temporaries
+# 12.09 GB against 5.88 GB of persistent state.  Every dryrun cell's
+# train plan is the same under the serving phases' 2.5 GB (PERF.md).
+TRAIN_ACTIVATION_ALLOWANCE = 6.5e9
+SERVE_ACTIVATION_ALLOWANCE = 2.5e9
+
+
+def activation_allowance(training: bool) -> float:
+    return TRAIN_ACTIVATION_ALLOWANCE if training else SERVE_ACTIVATION_ALLOWANCE
+
+
 def strategy_device_bytes(graph: CompGraph, strategy: Strategy,
                           mesh: MeshSpec, training: bool,
-                          activation_allowance: float = 2.5e9) -> float:
-    total = activation_allowance
+                          activation_allowance_bytes: float | None = None
+                          ) -> float:
+    """Bytes a chip under ``strategy``: every node's persistent bytes plus
+    the phase's activation allowance (or ``activation_allowance_bytes``)."""
+    total = (activation_allowance(training)
+             if activation_allowance_bytes is None
+             else activation_allowance_bytes)
     for name, node in graph.nodes.items():
         total += node_device_bytes(node, strategy[name], mesh, training)
     return total

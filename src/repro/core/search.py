@@ -32,7 +32,10 @@ class SearchOptions:
     fsdp_variants: bool = True
     # HBM capacity budget per chip; None disables the Lagrangian loop.
     hbm_budget: float | None = 16 * 1024**3 * 0.85
-    activation_allowance: float = 2.5e9
+    # bytes a chip beyond the persistent count (node_device_bytes): the
+    # step's activations and XLA's own temporaries; None takes the
+    # phase's (cost_model.activation_allowance)
+    activation_allowance: float | None = None
     # Paper-faithful mode for Table-3-style comparisons.
     paper_faithful: bool = False
     # Inter-op (pipeline) search level — consumed by

@@ -45,11 +45,10 @@ from repro.models import model_module, strategy_to_plan, uniform_plan
 from repro.models.arch import SHAPES
 from repro.models.graph_export import export_graph
 from repro.optim import adamw_init
-from repro.plans import (batch_pspecs, cache_pspecs, dominant_unit_plan,
-                         param_pspecs, to_shardings)
+from repro.plans import (adamw_shardings, batch_pspecs, cache_pspecs,
+                         dominant_unit_plan, param_pspecs, to_shardings)
 from repro.serve import make_serve_fns
 from repro.train import TrainConfig, make_train_step
-from repro.optim.adamw import zero1_state_pspecs
 
 RESULTS = Path(__file__).resolve().parents[3] / "results" / "dryrun"
 
@@ -229,19 +228,7 @@ def dryrun_cell(arch_name: str, shape_name: str, *, multi_pod: bool,
     with use_mesh(mesh):
         if shape.kind == "train":
             opt_abs = jax.eval_shape(adamw_init, params_abs)
-            shapes_tree = jax.tree.map(lambda x: x.shape, params_abs)
-            z_specs = {
-                "m": zero1_state_pspecs(
-                    p_specs, shapes_tree,
-                    tuple(a for a in ("pod", "data") if a in mesh.axis_names),
-                    dict(zip(mesh.axis_names, mesh.devices.shape))),
-            }
-            z_specs["v"] = z_specs["m"]
-            o_sh = {"m": to_shardings(z_specs["m"], mesh, like=opt_abs["m"]),
-                    "v": to_shardings(z_specs["v"], mesh, like=opt_abs["v"]),
-                    "step": to_shardings(
-                        jax.tree.map(lambda _: jax.sharding.PartitionSpec(),
-                                     opt_abs["step"]), mesh)}
+            o_sh = adamw_shardings(p_sh, mesh)
             step = make_train_step(arch, plan, train_cfg)
             jitted = jax.jit(step, in_shardings=(p_sh, o_sh, b_sh),
                              out_shardings=(p_sh, o_sh, None),

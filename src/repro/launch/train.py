@@ -36,7 +36,8 @@ from repro.launch.mesh import host_mesh
 from repro.models import model_module
 from repro.models.arch import ShapeSpec
 from repro.optim import AdamWConfig, adamw_init
-from repro.plans import param_pspecs, resolve_plan, to_shardings
+from repro.plans import (adamw_shardings, param_pspecs, resolve_plan,
+                         to_shardings)
 from repro.train import TrainConfig, make_train_step
 
 
@@ -133,6 +134,21 @@ def weight_shards(params) -> list[dict]:
     return out
 
 
+def memory_line(pplan, compiled) -> str:
+    """The plan search's bytes a chip beside what XLA compiled the step
+    to hold: its arguments and its temporaries."""
+    counted = pplan.meta.get("phases", {}).get("train", {}).get(
+        "device_bytes")
+    said = "nothing" if counted is None else f"{counted / 1e9:.3f} GB"
+    mem = compiled.memory_analysis()
+    if mem is None:
+        return f"train: memory a chip: search counted {said}"
+    arg, tmp = mem.argument_size_in_bytes, mem.temp_size_in_bytes
+    return (f"train: memory a chip: search counted {said}; compiled step "
+            f"arguments {arg / 1e9:.3f} GB + temporaries {tmp / 1e9:.3f} GB "
+            f"= {(arg + tmp) / 1e9:.3f} GB")
+
+
 def run(argv=None) -> dict:
     """Search (or load) a plan, train, and return what happened:
     per-step ``history`` (every step is logged), ``compile_s`` (the
@@ -197,23 +213,26 @@ def run(argv=None) -> dict:
 
     p_sh = to_shardings(param_pspecs(params, arch, plan, stages=train_stages),
                         mesh, like=params)
-    # moments shard like their parameters; pinning the step's output
-    # shardings to the input ones keeps step 2 from recompiling
-    o_sh = {"m": p_sh, "v": p_sh,
-            "step": jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())}
+    # ZeRO-1 moments, as the plan search counts them; pinning the step's
+    # output shardings to the input ones keeps step 2 from recompiling
+    o_sh = adamw_shardings(p_sh, mesh)
     params = jax.device_put(params, p_sh)
     opt_state = jax.device_put(opt_state, o_sh)
     jit_step = jax.jit(step_fn, donate_argnums=(0, 1),
                        out_shardings=(p_sh, o_sh, None))
 
     history, step_times = [], []
+    compiled = None
     with use_mesh(mesh):
         for step in range(start_step, args.steps):
             data_step = (step % args.overfit_batches if args.overfit_batches
                          else step)
             batch = jax.tree.map(jnp.asarray, ds.batch_at(data_step))
             t0 = time.perf_counter()
-            params, opt_state, metrics = jit_step(params, opt_state, batch)
+            if compiled is None:
+                compiled = jit_step.lower(params, opt_state, batch).compile()
+                print(memory_line(pplan, compiled))
+            params, opt_state, metrics = compiled(params, opt_state, batch)
             m = {k: float(v) for k, v in metrics.items()}   # waits
             step_times.append(time.perf_counter() - t0)
             history.append({"step": step, **m})

@@ -25,8 +25,17 @@ from repro.core.graph import CompGraph, LayerNode, TensorSpec
 
 from .arch import ArchConfig, ShapeSpec
 
-A_BYTES = 2   # bf16 activations
-P_BYTES = 2   # bf16 params
+# Byte widths of the f32 the program runs: P_BYTES prices the parameters
+# (and so the memory count's grads, accumulator and moments, and the
+# gradient sync), A_BYTES every activation (the tensors between nodes,
+# the hidden tensors priced here by hand, the KV cache).
+A_BYTES = 4
+P_BYTES = 4
+
+
+def _norm_bytes(arch: ArchConfig) -> int:
+    """A norm's scale (none for a non-parametric norm)."""
+    return 0 if arch.nonparam_norm else arch.d_model * P_BYTES
 
 
 def _sizes(**kw) -> dict:
@@ -97,7 +106,7 @@ def phase_shape(phase: str, *, seq_len: int, batch: int,
     ``kv_quant`` (decode only) prices the cache read at the paged pool's
     stored width: ``"int8"`` means 1 byte/elem plus the amortized f32
     per-(token-slot, head) scale, so the dominant ``kv_bytes`` term
-    shrinks ~4x against the bf16 pool and the searched decode plan can
+    shrinks ~4x against the f32 pool and the searched decode plan can
     trade cache-sequence sharding away accordingly.
     """
     if phase == "train":
@@ -123,14 +132,15 @@ def _decoder_chain(b: _Builder, arch: ArchConfig, B: int, Sq: int, Skv: int,
     D, H, KH, hd = arch.d_model, arch.n_heads, arch.n_kv_heads, arch.hd
     T = B * Sq
     decode = b.kind == "decode"
-    act = TensorSpec.make(batch=B, seq=Sq, d_model=D)
+    act = TensorSpec.make(A_BYTES, batch=B, seq=Sq, d_model=D)
     act_b = act.bytes
     h_sizes = _sizes(batch=B, seq=Skv, d_model=D, heads=H, d_ff=arch.d_ff,
                      vocab=arch.vocab, expert=arch.n_experts)
 
     def norm(name):
         return b.node(name, "norm", act, flops=6 * T * D, act=2 * act_b,
-                      params=4 * D, dims=("batch", "seq", "d_model"),
+                      params=_norm_bytes(arch),
+                      dims=("batch", "seq", "d_model"),
                       extra={"dim_sizes": h_sizes})
 
     def residual(name, src_skip):
@@ -152,14 +162,15 @@ def _decoder_chain(b: _Builder, arch: ArchConfig, B: int, Sq: int, Skv: int,
         kv_bytes = 2 * B * kvt * KH * hd * kv_width
         core = 4 * B * H * Sq * kvt * hd
         proj = 2 * T * D * (H + 2 * KH) * hd
-        aout = TensorSpec.make(batch=B, seq=Sq, heads=H, hd=hd)
+        aout = TensorSpec.make(A_BYTES, batch=B, seq=Sq, heads=H, hd=hd)
         # decode: the dominant tensor is the persistent KV cache, which has
         # only KH heads — cap the heads degree so memory accounting and
         # realization agree (beyond KH the cache would replicate).
         sizes = h_sizes if not decode else {**h_sizes, "heads": min(H, KH)}
         b.node(f"{prefix}L{i}.{tag}", "cross_attn" if cross else "attn",
                aout, flops=proj + core,
-               params=(D * (H + 2 * KH) * hd) * P_BYTES,
+               params=((D + (1 if arch.qkv_bias else 0)) * (H + 2 * KH) * hd
+                       * P_BYTES),
                act=(2 * act_b + 3 * aout.bytes + kv_bytes + kv_bytes),
                dims=("batch", "seq", "heads"),
                extra={"kv_bytes": float(kv_bytes), "decode": decode,
@@ -193,7 +204,7 @@ def _decoder_chain(b: _Builder, arch: ArchConfig, B: int, Sq: int, Skv: int,
                           "dim_sizes": {**h_sizes, "d_ff": fe}})
         else:
             f = arch.d_ff
-            hid = TensorSpec.make(batch=B, seq=Sq, d_ff=f)
+            hid = TensorSpec.make(A_BYTES, batch=B, seq=Sq, d_ff=f)
             b.node(f"{prefix}L{i}.mlp_in", "mlp_in", hid,
                    flops=4 * T * D * f, params=2 * D * f * P_BYTES,
                    act=2 * act_b + 2 * hid.bytes,
@@ -249,11 +260,11 @@ def _head(b: _Builder, arch: ArchConfig, B: int, Sq: int):
     D, V = arch.d_model, arch.vocab
     T = B * Sq
     b.unit = arch.n_units            # head rides the last stage
-    act = TensorSpec.make(batch=B, seq=Sq, d_model=D)
+    act = TensorSpec.make(A_BYTES, batch=B, seq=Sq, d_model=D)
     b.node("final_norm", "norm", act, flops=6 * T * D, act=2 * act.bytes,
-           params=4 * D, dims=("batch", "seq", "d_model"),
+           params=_norm_bytes(arch), dims=("batch", "seq", "d_model"),
            extra={"dim_sizes": _sizes(batch=B, seq=Sq, d_model=D)})
-    logits = TensorSpec.make(batch=B, seq=Sq, vocab=V)
+    logits = TensorSpec.make(A_BYTES, batch=B, seq=Sq, vocab=V)
     b.node("lm_head", "lm_head", logits, flops=2 * T * D * V,
            params=0 if arch.tie_embeddings else D * V * P_BYTES,
            act=act.bytes + logits.bytes * 2,
@@ -270,14 +281,14 @@ def _export_decoder(arch: ArchConfig, shape: ShapeSpec) -> CompGraph:
     T = B * Sq
     b = _Builder(arch, shape)
     b.unit = -1                      # entry nodes ride stage 0
-    act = TensorSpec.make(batch=B, seq=Sq, d_model=D)
+    act = TensorSpec.make(A_BYTES, batch=B, seq=Sq, d_model=D)
     b.node("embed", "embed", act, flops=2 * T * D,
            params=V * D * P_BYTES, act=3 * act.bytes,
            dims=("batch", "seq", "d_model", "vocab"),
            extra={"dim_sizes": _sizes(batch=B, seq=Sq, d_model=D, vocab=V)})
     if arch.frontend and not decode:
         F = arch.frontend_tokens
-        fr = TensorSpec.make(batch=B, seq=F, d_model=D)
+        fr = TensorSpec.make(A_BYTES, batch=B, seq=F, d_model=D)
         b.node("frontend", "stub", fr, flops=0, act=fr.bytes,
                dims=("batch", "seq", "d_model"),
                extra={"dim_sizes": _sizes(batch=B, seq=F, d_model=D)},
@@ -297,7 +308,7 @@ def _export_decoder(arch: ArchConfig, shape: ShapeSpec) -> CompGraph:
 
 def _export_encdec(arch: ArchConfig, shape: ShapeSpec) -> CompGraph:
     """Encoder chain feeds the decoder entry; memory re-layout between
-    decoder layers is charged inside each cross_attn node (see DESIGN.md)."""
+    decoder layers is charged inside each cross_attn node."""
     from .plan import _enc_view
 
     B = shape.global_batch
@@ -311,7 +322,7 @@ def _export_encdec(arch: ArchConfig, shape: ShapeSpec) -> CompGraph:
 
     b = _Builder(arch, shape)
     b.unit = -1                      # enc-dec graphs are not stageable yet
-    enc_act = TensorSpec.make(batch=B, seq=Se, d_model=D)
+    enc_act = TensorSpec.make(A_BYTES, batch=B, seq=Se, d_model=D)
     b.node("enc_in", "stub", enc_act, flops=2 * B * Se * D * D,
            params=D * D * P_BYTES, act=3 * enc_act.bytes,
            dims=("batch", "seq", "d_model"),
@@ -324,12 +335,12 @@ def _export_encdec(arch: ArchConfig, shape: ShapeSpec) -> CompGraph:
     _decoder_chain(b, enc_arch, B, Se, Se, prefix="enc.")
     b.kind = saved
     b.node("enc_norm", "norm", enc_act, flops=6 * B * Se * D,
-           act=2 * enc_act.bytes, params=4 * D,
+           act=2 * enc_act.bytes, params=_norm_bytes(arch),
            dims=("batch", "seq", "d_model"),
            extra={"dim_sizes": _sizes(batch=B, seq=Se, d_model=D)})
     enc_out = b.last
 
-    act = TensorSpec.make(batch=B, seq=Sq, d_model=D)
+    act = TensorSpec.make(A_BYTES, batch=B, seq=Sq, d_model=D)
     b.node("embed", "embed", act, flops=2 * B * Sq * D,
            params=V * D * P_BYTES, act=3 * act.bytes,
            dims=("batch", "seq", "d_model", "vocab"),

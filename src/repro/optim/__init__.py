@@ -4,8 +4,7 @@ from .adamw import (
     adamw_update,
     cosine_schedule,
     global_norm,
-    zero1_state_pspecs,
 )
 
 __all__ = ["AdamWConfig", "adamw_init", "adamw_update", "cosine_schedule",
-           "global_norm", "zero1_state_pspecs"]
+           "global_norm"]

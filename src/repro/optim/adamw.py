@@ -1,21 +1,17 @@
 """AdamW + cosine schedule + global-norm clipping, pure JAX.
 
 Optimizer moments are kept in f32 regardless of the parameter dtype.
-``zero1_state_pspecs`` produces ZeRO-1 shardings: each moment tensor is
-additionally sharded over the data axes along its largest divisible dim, so
-optimizer state does not replicate across data-parallel replicas (the
-distributed-optimization trick the 16-GiB/chip budget requires at 398B
-scale).  XLA inserts the all-gather on use / reduce-scatter on update.
+Their ZeRO-1 layout (split over the data axes as well as the parameter's
+own) is ``repro.plans.adamw_shardings``; XLA inserts the collectives the
+update then needs.
 """
 
 from __future__ import annotations
 
-import math
 from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
 
 @dataclass(frozen=True)
@@ -91,40 +87,3 @@ def adamw_update(params, grads, state, cfg: AdamWConfig):
                          is_leaf=lambda x: isinstance(x, tuple))
     metrics = {"grad_norm": gnorm, "lr": lr}
     return new_params, {"m": new_m, "v": new_v, "step": step}, metrics
-
-
-# --------------------------------------------------------------------------- #
-# ZeRO-1: shard the moments over the data axes
-# --------------------------------------------------------------------------- #
-def zero1_state_pspecs(param_pspecs, params_shapes, data_axes: tuple[str, ...],
-                       mesh_axis_sizes: dict[str, int]):
-    """Given the params' PartitionSpecs (pytree of P) and shapes, return
-    moment PartitionSpecs with the data axes added on the largest dim whose
-    spec entry is free and whose size is divisible by the data degree."""
-    ddeg = math.prod(mesh_axis_sizes[a] for a in data_axes)
-
-    def one(spec: P, shape):
-        if not shape:
-            return spec
-        entries = list(spec) + [None] * (len(shape) - len(spec))
-        used = set()
-        for e in entries:
-            if e is None:
-                continue
-            used.update(e if isinstance(e, tuple) else (e,))
-        free_axes = tuple(a for a in data_axes if a not in used)
-        deg = math.prod(mesh_axis_sizes[a] for a in free_axes) if free_axes else 1
-        if deg <= 1:
-            return spec
-        # pick the largest free, divisible dim
-        cands = [(shape[i], i) for i in range(len(shape))
-                 if entries[i] is None and shape[i] % deg == 0]
-        if not cands:
-            return spec
-        _, i = max(cands)
-        entries[i] = free_axes if len(free_axes) > 1 else free_axes[0]
-        return P(*entries)
-
-    return jax.tree.map(
-        one, param_pspecs, params_shapes,
-        is_leaf=lambda x: isinstance(x, P))

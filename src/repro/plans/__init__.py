@@ -30,6 +30,7 @@ from .search import (
     search_phase_plan,
 )
 from .shardings import (
+    adamw_shardings,
     batch_pspecs,
     cache_pspecs,
     dominant_unit_plan,
@@ -40,7 +41,7 @@ from .shardings import (
 __all__ = [
     "PHASES", "SCHEMA_VERSION", "STRATEGIES", "ParallelPlan",
     "PlanArchMismatchError", "PlanError", "PlanFormatError",
-    "arch_fingerprint", "as_model_plan", "baseline_phase_plan",
+    "adamw_shardings", "arch_fingerprint", "as_model_plan", "baseline_phase_plan",
     "batch_pspecs", "build_parallel_plan", "cache_pspecs",
     "dominant_unit_plan", "model_plan_from_json", "model_plan_to_json",
     "param_pspecs", "resolve_plan", "search_phase_plan", "to_shardings",

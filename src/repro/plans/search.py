@@ -95,6 +95,8 @@ def search_phase_plan(arch: ArchConfig, mesh: MeshSpec, phase: str, *,
                       "kv_quant": shape.kv_quant},
         "cost_s": strat.cost,
         "search_seconds": strat.meta.get("search_seconds"),
+        # the memory count's bytes a chip (persistent state + allowance)
+        "device_bytes": strat.meta.get("device_bytes"),
     }
     if profile is not None:
         prov["device_profile"] = profile.fingerprint()
@@ -309,6 +311,9 @@ def resolve_plan(arch: ArchConfig, mesh: MeshSpec | None, *,
             cost = pm.get("cost_s")
             if cost is not None:
                 log(f"plan: {phase} cost model {cost:.6f}s/step")
+            if pm.get("device_bytes") is not None:
+                log(f"plan: {phase} memory count "
+                    f"{pm['device_bytes'] / 1e9:.3f} GB a chip")
             if pm.get("stage_count", 1) > 1:
                 log(f"plan: {phase} pipeline S={pm['stage_count']} "
                     f"M={plan.stage_for(phase).microbatches} "

@@ -12,11 +12,13 @@ activations out per segment, and XLA reshards the few boundary parameters.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.config import LayerConfig
+from repro.core.config import LayerConfig, zero1_axes
 from repro.core.sharding import pspec
 from repro.models.arch import ArchConfig
 from repro.models.plan import ModelPlan, UnitPlan
@@ -83,6 +85,28 @@ def dominant_unit_plan(segments) -> UnitPlan | None:
     return max(segments, key=lambda s: s.n_units).plan
 
 
+def spec_axes(spec: P) -> set[str]:
+    """The mesh axes ``spec`` splits over."""
+    return {a for e in spec if e is not None
+            for a in (e if isinstance(e, tuple) else (e,))}
+
+
+def split_free_dim(spec: P, shape, axes, axis_sizes) -> P | None:
+    """``spec`` with ``axes`` added, as one entry, on the largest
+    dimension of ``shape`` that ``spec`` leaves whole and that their
+    degree divides; None where no dimension does.  FSDP stores a
+    parameter so (:func:`param_pspecs`), ZeRO-1 its moments
+    (:func:`adamw_shardings`)."""
+    entries = list(spec) + [None] * (len(shape) - len(spec))
+    deg = math.prod(axis_sizes[a] for a in axes)
+    free = [(shape[i], i) for i, e in enumerate(entries)
+            if e is None and shape[i] % deg == 0]
+    if not free:
+        return None
+    entries[max(free)[1]] = axes if len(axes) > 1 else axes[0]
+    return P(*entries)
+
+
 def param_pspecs(params, arch: ArchConfig, plan: ModelPlan, *,
                  stages=None):
     """Pytree of PartitionSpec mirroring ``params``.
@@ -107,25 +131,15 @@ def param_pspecs(params, arch: ArchConfig, plan: ModelPlan, *,
         axes onto the largest free divisible dim of the stored param."""
         if not cfg.fsdp:
             return spec
-        entries = list(spec) + [None] * (len(shape) - len(spec))
-        used: set[str] = set()
-        for e in entries:
-            if e is None:
-                continue
-            used.update(e if isinstance(e, tuple) else (e,))
+        used = spec_axes(spec)
         # FSDP shards over every axis not already sharding this param —
         # including the batch axes (that is what makes it ZeRO-3).
         axes = tuple(a for a in ("pod", "data", "model")
                      if a in mesh_axis_sizes and a not in used)
-        import math as _m
         while axes:
-            deg = _m.prod(mesh_axis_sizes[a] for a in axes)
-            cands = [(shape[i], i) for i in range(len(shape))
-                     if entries[i] is None and shape[i] % deg == 0]
-            if cands:
-                _, i = max(cands)
-                entries[i] = axes if len(axes) > 1 else axes[0]
-                return P(*entries)
+            split = split_free_dim(spec, shape, axes, mesh_axis_sizes)
+            if split is not None:
+                return split
             axes = axes[:-1]
         return spec
 
@@ -234,12 +248,23 @@ def cache_pspecs(cache, arch: ArchConfig, plan: ModelPlan, *,
     return jax.tree_util.tree_map_with_path(leaf_spec, cache)
 
 
+class LeafSharding(NamedSharding):
+    """A NamedSharding that keeps the shape of the leaf it lays out, so
+    that a layout derived from it (the AdamW moments',
+    :func:`adamw_shardings`) can pick dimensions by size."""
+
+    def __init__(self, mesh: Mesh, spec: P, leaf_shape):
+        super().__init__(mesh, spec)
+        self.leaf_shape = tuple(leaf_shape)
+
+
 def to_shardings(pspecs, mesh: Mesh, like=None):
     """PartitionSpec pytree -> NamedSharding pytree.
 
     Drops axes not present in ``mesh``; when ``like`` (a matching pytree of
     arrays / ShapeDtypeStructs) is given, also drops entries whose shard
-    count exceeds the dim size (8 KV heads on a 16-way axis -> replicated).
+    count exceeds the dim size (8 KV heads on a 16-way axis -> replicated),
+    and returns :class:`LeafSharding` s, which keep their leaf's shape.
     """
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
 
@@ -264,10 +289,43 @@ def to_shardings(pspecs, mesh: Mesh, like=None):
                 entries.append(None)
                 continue
             entries.append(axes if len(axes) > 1 else axes[0])
-        return NamedSharding(mesh, P(*entries))
+        if leaf is None:
+            return NamedSharding(mesh, P(*entries))
+        return LeafSharding(mesh, P(*entries), leaf.shape)
 
     if like is None:
         return jax.tree.map(clean, pspecs,
                             is_leaf=lambda x: isinstance(x, P))
     return jax.tree.map(clean, pspecs, like,
                         is_leaf=lambda x: isinstance(x, P))
+
+
+def adamw_shardings(param_shardings, mesh: Mesh) -> dict:
+    """The AdamW state's layout for parameters laid out as
+    ``param_shardings`` (:class:`LeafSharding` s, as :func:`to_shardings`
+    gives them with ``like``).
+
+    ``m`` and ``v`` take their parameter's spec, split further (ZeRO-1)
+    over the data-parallel axes the parameter is not stored over
+    (:func:`repro.core.config.zero1_axes`, the rule the plan search's
+    memory count takes) by :func:`split_free_dim`; a leaf with no such
+    dimension keeps its parameter's layout.  ``step`` is replicated.
+    Where no such axis is larger than 1 (one device) the moments take
+    the parameters' shardings themselves.
+    """
+    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+
+    def moment(sh: NamedSharding) -> NamedSharding:
+        axes = tuple(a for a in zero1_axes(spec_axes(sh.spec), mesh.axis_names)
+                     if sizes[a] > 1)
+        if not axes:
+            return sh
+        if not isinstance(sh, LeafSharding):
+            raise TypeError("adamw_shardings splits moments by their "
+                            "leaves' shapes: pass the LeafShardings of "
+                            "to_shardings(..., like=params)")
+        split = split_free_dim(sh.spec, sh.leaf_shape, axes, sizes)
+        return sh if split is None else NamedSharding(mesh, split)
+
+    moments = jax.tree.map(moment, param_shardings)
+    return {"m": moments, "v": moments, "step": NamedSharding(mesh, P())}

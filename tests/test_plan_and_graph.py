@@ -9,7 +9,7 @@ from repro.core import (LayerConfig, find_strategy, single_pod_mesh_spec,
                         uniform_strategy)
 from repro.models import strategy_to_plan, uniform_plan
 from repro.models.arch import SHAPES
-from repro.models.graph_export import export_graph
+from repro.models.graph_export import P_BYTES, export_graph
 from repro.models.plan import sublayer_keys
 
 
@@ -25,7 +25,7 @@ def test_graph_exports_and_reduces(name, shape_name):
     # every non-source node reachable; flops non-negative; param bytes sane
     assert g.num_edges >= g.num_nodes - 2
     total_params = sum(n.param_bytes for n in g.nodes.values())
-    expected = arch.param_count()["total"] * 2  # bf16
+    expected = arch.param_count()["total"] * P_BYTES  # f32
     assert total_params == pytest.approx(expected, rel=0.35)
     # strategy search reduces the graph completely
     mesh = single_pod_mesh_spec(2, 2)

@@ -40,7 +40,7 @@ from repro.core.device import AxisSpec, ICI_BW, MeshSpec
 from repro.kernels import ops
 from repro.kernels.quant import dequantize_kv, quantize_kv
 from repro.models import lm
-from repro.models.graph_export import export_graph, phase_shape
+from repro.models.graph_export import A_BYTES, export_graph, phase_shape
 from repro.serve import Request, ServeConfig, ServeEngine
 from repro.serve.engine import copy_block
 
@@ -390,9 +390,9 @@ def test_graph_export_prices_int8_cache_read():
     for n in attn:
         fp_kv = g_fp.nodes[n].extra["kv_bytes"]
         q_kv = g_q.nodes[n].extra["kv_bytes"]
-        # fp prices A_BYTES=2 per element; int8 prices 1 + 4/hd
+        # fp prices A_BYTES per element (f32: 4); int8 prices 1 + 4/hd
         assert q_kv == pytest.approx(
-            fp_kv * (1.0 + 4.0 / arch.hd) / 2.0)
+            fp_kv * (1.0 + 4.0 / arch.hd) / A_BYTES)
     # prefill graphs are untouched by kv_quant (quantize-on-write only
     # narrows the decode-time cache read)
     p_fp = export_graph(arch, phase_shape("prefill", seq_len=512, batch=4))
@@ -409,22 +409,27 @@ def test_searched_decode_plan_shifts_under_int8_pricing():
     axis cannot hide in head sharding) the int8-priced decode search
     must return a strictly cheaper cost AND a different assignment than
     the fp-priced search — the quantized cache read genuinely changes
-    the plan, not just its price tag."""
+    the plan, not just its price tag: the mixed step of 4 slots at 8
+    query tokens splits the f32 cache's sequence over ``model`` and
+    stops splitting the int8 cache, whose read no longer pays for it."""
     from repro.core.search import find_strategy
     from repro.launch.train import reduced_arch
 
     arch = dataclasses.replace(
-        reduced_arch(C.get("llama3.2-1b"), 256, 4, 512, 4), n_kv_heads=1)
+        reduced_arch(C.get("llama3.2-1b"), 1024, 4, 512, 4), n_kv_heads=1)
     mesh = MeshSpec(axes=(AxisSpec("data", 4, ICI_BW),
                           AxisSpec("model", 2, ICI_BW)))
     strat = {}
     for kvq in (None, "int8"):
-        shape = phase_shape("decode", seq_len=8192, batch=32,
-                            kv_tokens=8192, kv_quant=kvq)
+        shape = phase_shape("decode", seq_len=2048, batch=4,
+                            kv_tokens=2048, q_tokens=8, kv_quant=kvq)
         strat[kvq] = find_strategy(export_graph(arch, shape), mesh,
                                    phase="decode")
     assert strat["int8"].cost < strat[None].cost
     assert strat["int8"].assignment != strat[None].assignment
+    attn = [n for n in strat[None].assignment if n.endswith(".attn")]
+    assert all(strat[None][n].axes_for("seq") for n in attn)
+    assert not any(strat["int8"][n].axes_for("seq") for n in attn)
 
 
 def test_plan_meta_records_and_roundtrips_kv_quant(tmp_path):
