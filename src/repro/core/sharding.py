@@ -12,6 +12,7 @@ stays mesh-agnostic (a no-op on a single device — smoke tests see no mesh).
 from __future__ import annotations
 
 import contextlib
+import math
 import threading
 from typing import Sequence
 
@@ -80,39 +81,47 @@ def sharding(cfg: LayerConfig, dims: Sequence[DimName],
     return NamedSharding(mesh, P(*cleaned))
 
 
+def entry_axes(entry) -> tuple[str, ...]:
+    """The mesh axes one PartitionSpec entry names, major first."""
+    if entry is None:
+        return ()
+    return entry if isinstance(entry, tuple) else (entry,)
+
+
+def fitted_pspec(shape: Sequence[int], cfg: LayerConfig,
+                 dims: Sequence[DimName], mesh: Mesh | None = None
+                 ) -> P | None:
+    """The spec :func:`constrain` applies to an array of ``shape`` under
+    ``mesh`` (default: the active one; None without a mesh).
+
+    Entries whose shard count does not divide the array dim lose axes,
+    left-first, until it does (e.g. 8 KV heads on a 16-way model axis ->
+    replicated KV, the standard GQA-TP fallback), so every shard is the
+    same size.
+    """
+    mesh = mesh if mesh is not None else current_mesh()
+    if mesh is None:
+        return None
+    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    entries = []
+    for dim_size, entry in zip(shape, sharding(cfg, dims, mesh).spec):
+        axes = entry_axes(entry)
+        while axes and dim_size % math.prod(sizes[a] for a in axes):
+            axes = axes[1:]
+        entries.append(None if not axes
+                       else axes if len(axes) > 1 else axes[0])
+    return P(*entries)
+
+
 def constrain(x: jax.Array, cfg: LayerConfig,
               dims: Sequence[DimName]) -> jax.Array:
-    """``with_sharding_constraint`` under the active mesh (no-op without).
-
-    Entries whose shard count exceeds the array dim are dropped (e.g. 8 KV
-    heads on a 16-way model axis -> replicated KV, the standard GQA-TP
-    fallback); uneven-but-smaller sharding is kept (GSPMD pads).
-    """
+    """``with_sharding_constraint`` to :func:`fitted_pspec` under the
+    active mesh (no-op without)."""
     mesh = current_mesh()
     if mesh is None:
         return x
-    s = sharding(cfg, dims, mesh)
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    entries = []
-    for dim_size, entry in zip(x.shape, s.spec):
-        if entry is None:
-            entries.append(None)
-            continue
-        axes = entry if isinstance(entry, tuple) else (entry,)
-        # drop axes (left-first) until the dim divides evenly
-        while axes:
-            deg = 1
-            for a in axes:
-                deg *= sizes[a]
-            if dim_size % deg == 0:
-                break
-            axes = axes[1:]
-        if not axes:
-            entries.append(None)
-        else:
-            entries.append(axes if len(axes) > 1 else axes[0])
     return jax.lax.with_sharding_constraint(
-        x, NamedSharding(mesh, P(*entries)))
+        x, NamedSharding(mesh, fitted_pspec(x.shape, cfg, dims, mesh)))
 
 
 def constrain_tree(tree, cfg: LayerConfig, dims_tree) -> object:

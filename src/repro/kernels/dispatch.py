@@ -18,14 +18,25 @@ Overrides, strongest first:
   3. ``REPRO_KERNEL_BACKEND_<OP>`` env var (op name upper-cased);
   4. ``REPRO_KERNEL_BACKEND`` env var;
   5. automatic selection (highest-priority impl whose platform matches and
-     whose ``supports`` predicate accepts the arguments).  ``interpret``
-     (the Pallas interpreter) is a test harness: it runs only when asked
-     for by name, never as an automatic choice.
+     whose ``supports`` predicate accepts the arguments; under a mesh,
+     an impl that is not ``spmd_safe`` only where its ``local_specs``
+     maps the call, see below).  ``interpret`` (the Pallas interpreter)
+     is a test harness: it runs only when asked for by name, never as an
+     automatic choice.
+
+Under a multi-device mesh an impl that is not ``spmd_safe`` (an opaque
+custom call) runs only where the call passes ``specs=``, the
+PartitionSpecs its array arguments are sharded by, and the impl's
+``local_specs`` rule finds a split it can run shard by shard: the call
+is then wrapped in ``shard_map``, so every device runs the kernel on its
+own slice, and every gate is checked on the local shapes.  Elsewhere it
+is passed over as in step 5.
 
 Every resolution is counted per (op, backend) — :func:`selection_counts`
-— and every spmd-unsafe impl passed over under a mesh is counted in
-:func:`spmd_skips`, so a run can show which kernels it actually executed.
-Selection happens at trace time: the counts are traces, not calls.
+— every one run shard by shard also in :func:`spmd_maps`, and every
+spmd-unsafe impl passed over under a mesh in :func:`spmd_skips`, so a
+run can show which kernels it actually executed.  Selection happens at
+trace time: the counts are traces, not calls.
 
 Ops registered by the sibling modules (canonical layouts/signatures):
   flash_attention(q, k, v, *, causal, block_q, block_k)
@@ -51,8 +62,10 @@ Ops registered by the sibling modules (canonical layouts/signatures):
 from __future__ import annotations
 
 import contextlib
+import functools
 import json
 import logging
+import math
 import os
 import re
 import threading
@@ -65,6 +78,7 @@ from typing import Any, Callable
 import jax
 
 from repro import compat
+from repro.core.sharding import current_mesh, entry_axes
 
 log = logging.getLogger(__name__)
 
@@ -88,9 +102,16 @@ class Impl:
     auto_gate: Callable[..., bool] | None = None
     # False for impls that lower to an opaque custom call (pallas_call)
     # with no SPMD partitioning rule: under a multi-device mesh GSPMD
-    # would replicate their operands (all-gathering full q/k/v), so
-    # auto-selection skips them there; an explicit backend= still wins.
+    # would replicate their operands (all-gathering full q/k/v).  There
+    # such an impl runs shard-mapped where ``local_specs`` accepts the
+    # call's specs; otherwise auto-selection skips it, and an explicit
+    # backend= still wins, run unmapped.
     spmd_safe: bool = True
+    # local_specs(sizes, specs, *args, **kwargs) -> (in_specs, out_specs)
+    # or None: ``sizes`` maps mesh axis -> devices, ``specs`` are the
+    # PartitionSpecs of the positional arrays.  A result means each
+    # device computes its own output shard from its input shards alone.
+    local_specs: Callable[..., Any] | None = None
 
     def eligible(self, platform: str, args, kwargs, *,
                  auto: bool = True) -> bool:
@@ -118,7 +139,8 @@ _registered_builtins = False
 def register(op: str, backend: str, *, platforms: tuple[str, ...] = ("*",),
              priority: int = 0, supports: Callable[..., bool] | None = None,
              auto_gate: Callable[..., bool] | None = None,
-             spmd_safe: bool = True):
+             spmd_safe: bool = True,
+             local_specs: Callable[..., Any] | None = None):
     """Decorator: register ``fn`` as the ``backend`` implementation of
     ``op``.  Re-registration replaces (module reloads)."""
 
@@ -126,7 +148,7 @@ def register(op: str, backend: str, *, platforms: tuple[str, ...] = ("*",),
         _REGISTRY.setdefault(op, {})[backend] = Impl(
             op=op, backend=backend, fn=fn, platforms=tuple(platforms),
             priority=priority, supports=supports, auto_gate=auto_gate,
-            spmd_safe=spmd_safe)
+            spmd_safe=spmd_safe, local_specs=local_specs)
         return fn
 
     return deco
@@ -172,10 +194,40 @@ def force_backend(backend: str | None):
 
 def _mesh_active() -> bool:
     """True when a multi-device mesh is active (``use_mesh``): SPMD
-    partitioning is in play and spmd-unsafe impls must not auto-select."""
-    from repro.core.sharding import current_mesh
+    partitioning is in play and spmd-unsafe impls run only shard-mapped."""
     mesh = current_mesh()
     return mesh is not None and mesh.devices.size > 1
+
+
+@dataclass(frozen=True)
+class _Local:
+    """How a call runs shard by shard: the specs for ``shard_map`` and
+    the per-device arguments the gates are checked on."""
+    in_specs: tuple
+    out_specs: Any
+    args: tuple
+
+
+def _local(impl: Impl, specs, args, kwargs) -> _Local | None:
+    """The shard-by-shard form of this call, if ``impl`` has one for the
+    given ``specs`` on the active mesh."""
+    mesh = current_mesh()
+    if impl.local_specs is None or specs is None or mesh is None:
+        return None
+    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    mapped = impl.local_specs(sizes, tuple(specs), *args, **kwargs)
+    if mapped is None:
+        return None
+    in_specs, out_specs = mapped
+    local = []
+    for x, spec in zip(args, in_specs):
+        degs = [math.prod(sizes[a] for a in entry_axes(e)) for e in spec]
+        degs += [1] * (x.ndim - len(degs))
+        if any(n % d for n, d in zip(x.shape, degs)):
+            return None
+        local.append(jax.ShapeDtypeStruct(
+            tuple(n // d for n, d in zip(x.shape, degs)), x.dtype))
+    return _Local(tuple(in_specs), out_specs, tuple(local))
 
 
 def _override_for(op: str) -> str | None:
@@ -186,7 +238,8 @@ def _override_for(op: str) -> str | None:
             or os.environ.get(ENV_GLOBAL) or None)
 
 
-def select(op: str, *args, backend: str | None = None, **kwargs) -> Impl:
+def select(op: str, *args, backend: str | None = None, specs=None,
+           **kwargs) -> Impl:
     """Resolve the implementation that :func:`call` would run.
 
     An explicit ``backend=`` is strict: ineligible -> ValueError.  A
@@ -195,20 +248,33 @@ def select(op: str, *args, backend: str | None = None, **kwargs) -> Impl:
     that cannot handle this particular call (e.g. the stateless Pallas
     wkv6 asked for the stateful decode form) logs a warning and falls
     through to auto-selection, so one override can steer a whole model
-    without crashing the ops it cannot cover.
+    without crashing the ops it cannot cover.  ``specs`` are the
+    PartitionSpecs of the positional arrays under the active mesh (see
+    the module docstring).
     """
+    return _resolve(op, args, kwargs, backend, specs)[0]
+
+
+def _resolve(op: str, args, kwargs, backend: str | None,
+             specs) -> tuple[Impl, _Local | None]:
     impls = backends(op)
     platform = compat.default_platform()
     strict = backend is not None
     backend = backend or _override_for(op)
+    spmd = _mesh_active()
     if backend is not None:
         if backend not in impls:
             raise ValueError(
                 f"backend {backend!r} not registered for op {op!r} "
                 f"(have: {sorted(impls)})")
         impl = impls[backend]
+        local = (_local(impl, specs, args, kwargs)
+                 if spmd and not impl.spmd_safe else None)
+        if local is not None and impl.eligible(platform, local.args, kwargs,
+                                               auto=False):
+            return impl, local
         if impl.eligible(platform, args, kwargs, auto=False):
-            return impl
+            return impl, None
         if strict:
             raise ValueError(
                 f"backend {backend!r} for op {op!r} does not support "
@@ -216,17 +282,20 @@ def select(op: str, *args, backend: str | None = None, **kwargs) -> Impl:
         log.warning("forced backend %r cannot handle this %r call; "
                     "auto-selecting", backend, op)
     ranked = sorted(impls.values(), key=lambda i: -i.priority)
-    spmd = _mesh_active()
     for impl in ranked:
         if impl.backend == "interpret":
             continue
-        if not impl.eligible(platform, args, kwargs):
-            continue
         if spmd and not impl.spmd_safe:
-            with _COUNT_LOCK:
-                _SPMD_SKIPS[(op, impl.backend)] += 1
+            local = _local(impl, specs, args, kwargs)
+            if local is not None and impl.eligible(platform, local.args,
+                                                   kwargs):
+                return impl, local
+            if impl.eligible(platform, args, kwargs):
+                with _COUNT_LOCK:
+                    _SPMD_SKIPS[(op, impl.backend)] += 1
             continue
-        return impl
+        if impl.eligible(platform, args, kwargs):
+            return impl, None
     raise RuntimeError(
         f"no eligible backend for op {op!r} on platform {platform!r}; "
         f"registered: {sorted(impls)}")
@@ -235,13 +304,22 @@ def select(op: str, *args, backend: str | None = None, **kwargs) -> Impl:
 _COUNT_LOCK = threading.Lock()
 _SELECTIONS: Counter = Counter()       # (op, backend) -> resolutions
 _SPMD_SKIPS: Counter = Counter()       # (op, backend) -> passed over
+_SPMD_MAPS: Counter = Counter()        # (op, backend) -> run shard-mapped
 
 
-def call(op: str, *args, backend: str | None = None, **kwargs):
-    """Dispatch ``op`` to the selected backend implementation."""
-    impl = select(op, *args, backend=backend, **kwargs)
+def call(op: str, *args, backend: str | None = None, specs=None,
+         **kwargs):
+    """Dispatch ``op`` to the selected backend implementation; ``specs``
+    as in :func:`select`."""
+    impl, local = _resolve(op, args, kwargs, backend, specs)
     record_selection(op, impl.backend)
-    return impl.fn(*args, **kwargs)
+    if local is None:
+        return impl.fn(*args, **kwargs)
+    with _COUNT_LOCK:
+        _SPMD_MAPS[(op, impl.backend)] += 1
+    return compat.shard_map(
+        functools.partial(impl.fn, **kwargs), mesh=current_mesh(),
+        in_specs=local.in_specs, out_specs=local.out_specs)(*args)
 
 
 def record_selection(op: str, backend: str) -> None:
@@ -266,10 +344,18 @@ def spmd_skips() -> dict[tuple[str, str], int]:
         return dict(_SPMD_SKIPS)
 
 
+def spmd_maps() -> dict[tuple[str, str], int]:
+    """(op, backend) pairs that ran shard-mapped under a multi-device
+    mesh (each also counts in :func:`selection_counts`)."""
+    with _COUNT_LOCK:
+        return dict(_SPMD_MAPS)
+
+
 def reset_selection_counts() -> None:
     with _COUNT_LOCK:
         _SELECTIONS.clear()
         _SPMD_SKIPS.clear()
+        _SPMD_MAPS.clear()
 
 
 # --------------------------------------------------------------------------- #

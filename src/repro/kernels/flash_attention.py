@@ -34,8 +34,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 from repro import compat
+from repro.core.sharding import entry_axes
 
 from . import dispatch, mha_xla
 
@@ -460,9 +462,30 @@ def _via_pallas(q, k, v, *, causal=True, block_q=None, block_k=None,
     return _grad_ready(causal, bq, bk, interpret)(q, k, v)
 
 
+def _local_specs(sizes, specs, q, k, v, **kwargs):
+    """Shard-by-shard attention under a mesh: batch split alike on q and
+    k/v, heads split alike and no further than KH divides (so each
+    device's query heads attend to its own KV heads: head h -> h // G
+    holds per shard), sequence and head_dim whole.  None otherwise, e.g.
+    a sequence split, or heads split past KH (the replicated-KV GQA
+    fallback), where a shard would need other shards' keys."""
+    q_spec, k_spec, v_spec = (tuple(s) + (None,) * (4 - len(s))
+                              for s in specs)
+    if k_spec != v_spec or q_spec[0] != k_spec[0] or q_spec[1] != k_spec[1]:
+        return None
+    if any(e is not None for e in q_spec[2:] + k_spec[2:]):
+        return None
+    heads = math.prod(sizes[a] for a in entry_axes(q_spec[1]))
+    if q.shape[1] % heads or k.shape[1] % heads:
+        return None
+    return tuple(P(*s) for s in (q_spec, k_spec, v_spec)), P(*q_spec)
+
+
 dispatch.register("flash_attention", "pallas", platforms=("tpu",),
-                  priority=100, supports=_supports_native, spmd_safe=False)(
+                  priority=100, supports=_supports_native, spmd_safe=False,
+                  local_specs=_local_specs)(
     functools.partial(_via_pallas, interpret=False))
 dispatch.register("flash_attention", "interpret",
-                  priority=20, supports=_supports, spmd_safe=False)(
+                  priority=20, supports=_supports, spmd_safe=False,
+                  local_specs=_local_specs)(
     functools.partial(_via_pallas, interpret=True))

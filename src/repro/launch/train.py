@@ -134,6 +134,18 @@ def weight_shards(params) -> list[dict]:
     return out
 
 
+def kernels_line(pplan) -> str:
+    """The plan beside the kernels its step traced shard-mapped under the
+    mesh and those passed over there (spmd-unsafe, split they cannot
+    take)."""
+    def said(counts):
+        return ", ".join(f"{op}/{b} x{n}" for (op, b), n in
+                         sorted(counts.items())) or "none"
+    return (f"train: plan {pplan.strategy_name}; shard-mapped kernels "
+            f"{said(kernel_dispatch.spmd_maps())}; passed over "
+            f"{said(kernel_dispatch.spmd_skips())}")
+
+
 def memory_line(pplan, compiled) -> str:
     """The plan search's bytes a chip beside what XLA compiled the step
     to hold: its arguments and its temporaries."""
@@ -230,8 +242,10 @@ def run(argv=None) -> dict:
             batch = jax.tree.map(jnp.asarray, ds.batch_at(data_step))
             t0 = time.perf_counter()
             if compiled is None:
+                kernel_dispatch.reset_selection_counts()
                 compiled = jit_step.lower(params, opt_state, batch).compile()
                 print(memory_line(pplan, compiled))
+                print(kernels_line(pplan))
             params, opt_state, metrics = compiled(params, opt_state, batch)
             m = {k: float(v) for k, v in metrics.items()}   # waits
             step_times.append(time.perf_counter() - t0)

@@ -20,9 +20,10 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core.config import LayerConfig
-from repro.core.sharding import constrain
+from repro.core.sharding import constrain, current_mesh, fitted_pspec
 from repro.kernels import dispatch as kernel_dispatch
 from repro.kernels.mha_xla import mha_chunked as _mha_core  # noqa: F401
 from repro.kernels.quant import quantize_kv
@@ -336,9 +337,19 @@ def attention(p: dict, x: jax.Array, arch, cfg: LayerConfig,
                                      positions[..., -1] + 1)
             o = o.reshape(B, 1, H, hd)
         else:
+            # the specs ``constrain`` gave q and k/v, in kernel layout:
+            # under a mesh a Pallas kernel runs shard-mapped where they
+            # split no sequence and no more heads than KH
+            specs = None
+            if current_mesh() is not None:
+                dims = ("batch", "seq", "heads", None)
+                q_spec, kv_spec = (fitted_pspec(t.shape, cfg, dims)
+                                   for t in (q, k))
+                specs = tuple(P(sp[0], sp[2], sp[1], None)
+                              for sp in (q_spec, kv_spec, kv_spec))
             o = kernel_dispatch.call(
                 "flash_attention", q.transpose(0, 2, 1, 3), kt, vt,
-                causal=causal, block_q=q_chunk)
+                causal=causal, block_q=q_chunk, specs=specs)
             o = o.transpose(0, 2, 1, 3)
     else:
         # Cross-attention (free-form memory positions) and mid-sequence

@@ -15,6 +15,7 @@ import numpy as np
 import pytest
 
 from repro import compat
+from repro.core.sharding import use_mesh
 from repro.kernels import dispatch, ops, ref
 
 
@@ -365,14 +366,33 @@ def test_selection_counts_and_spmd_skips(monkeypatch):
                                            ("flash_attention", "xla"): 1}
     assert dispatch.spmd_skips() == {}
     # under a multi-device mesh the spmd-unsafe native kernel is passed
-    # over, and the skip is on record
+    # over where the call's specs cannot be mapped (a sequence split),
+    # and the skip is on record
     monkeypatch.setattr(compat, "default_platform", lambda: "tpu")
     monkeypatch.setattr(dispatch, "_mesh_active", lambda: True)
+    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1),
+                             ("data", "model"))
+    P = jax.sharding.PartitionSpec
+    seq_split = (P("data", None, "model"),) * 3
+    mappable = (P("data", "model"),) * 3
     q, k, v = _flash_args(S=256, D=128)
-    assert dispatch.select("flash_attention", q, k, v).backend != "pallas"
-    assert dispatch.spmd_skips() == {("flash_attention", "pallas"): 1}
+    with use_mesh(mesh):
+        assert dispatch.select("flash_attention", q, k, v,
+                               specs=seq_split).backend != "pallas"
+        assert dispatch.spmd_skips() == {("flash_attention", "pallas"): 1}
+        # where they can, it runs shard-mapped, counted in both tables
+        assert dispatch.select("flash_attention", q, k, v,
+                               specs=mappable).backend == "pallas"
+        dispatch.reset_selection_counts()
+        dispatch.call("flash_attention", q, k, v, specs=mappable,
+                      backend="interpret")
+    assert dispatch.spmd_maps() == {("flash_attention", "interpret"): 1}
+    assert dispatch.selection_counts() == {
+        ("flash_attention", "interpret"): 1}
+    assert dispatch.spmd_skips() == {}
     dispatch.reset_selection_counts()
-    assert dispatch.selection_counts() == {} and dispatch.spmd_skips() == {}
+    assert (dispatch.selection_counts() == {} and dispatch.spmd_skips() == {}
+            and dispatch.spmd_maps() == {})
 
 
 def test_chip_spec_by_device_kind():

@@ -172,12 +172,10 @@ def test_moe_dispatch_combine_compiles(one_chip):
              ((E, F, D), F32))
 
 
-def test_olmo_1b_train_step_fits_the_2x2_host(topology):
-    """olmo-1b at all 16 layers as the benchmark's 2x2 cell trains it:
-    16 x 2048 a step in 4 microbatches under the searched plan, with
-    ZeRO-1 moments.  XLA's arguments and temporaries a chip fit the
-    v5e's 15.75 GiB, and the plan search's count (persistent state plus
-    its activation allowance) holds them with at most 10% to spare."""
+def _olmo_2x2_step(topology):
+    """The searched train plan of olmo-1b at all 16 layers, as the
+    benchmark's 2x2 cell trains it (16 x 2048 a step in 4 microbatches,
+    ZeRO-1 moments), and its step compiled for the described host."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
@@ -208,8 +206,62 @@ def test_olmo_1b_train_step_fits_the_2x2_host(topology):
             step, in_shardings=(p_sh, o_sh, b_sh),
             out_shardings=(p_sh, o_sh, None), donate_argnums=(0, 1),
         ).lower(params, jax.eval_shape(adamw_init, params), batch).compile()
+    return pplan, compiled
+
+
+@pytest.fixture(scope="module")
+def olmo_xla_step(topology):
+    """The step as the CPU host selects its kernels: chunked-XLA
+    attention."""
+    return _olmo_2x2_step(topology)
+
+
+def _held(compiled) -> int:
     mem = compiled.memory_analysis()
-    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    return mem.argument_size_in_bytes + mem.temp_size_in_bytes
+
+
+def test_olmo_1b_train_step_fits_the_2x2_host(olmo_xla_step):
+    """olmo-1b at all 16 layers as the benchmark's 2x2 cell trains it:
+    16 x 2048 a step in 4 microbatches under the searched plan, with
+    ZeRO-1 moments.  XLA's arguments and temporaries a chip fit the
+    v5e's 15.75 GiB, and the plan search's count (persistent state plus
+    its activation allowance) holds them with at most 10% to spare."""
+    pplan, compiled = olmo_xla_step
+    held = _held(compiled)
     counted = pplan.meta["phases"]["train"]["device_bytes"]
     assert held < 15.75 * 2**30, held
     assert held <= counted <= 1.1 * held, (held, counted)
+
+
+def test_olmo_1b_train_step_maps_pallas_attention_on_the_2x2_host(
+        topology, olmo_xla_step, monkeypatch):
+    """With the platform a TPU, the same step runs the Pallas flash
+    attention, forward and blocked backward, shard-mapped on each chip's
+    slice: the plan splits attention by batch (layers 1-15) and by batch
+    and heads (layer 0), which the kernel can take locally.  It still
+    fits, gathers no more than the chunked-XLA step (so no q/k/v is
+    gathered whole around the kernel), and the search's plan does not
+    depend on the attention's backend."""
+    from repro import compat
+    xla_plan, xla_step = olmo_xla_step
+    monkeypatch.setattr(compat, "default_platform", lambda: "tpu")
+    dispatch.reset_selection_counts()
+    pplan, compiled = _olmo_2x2_step(topology)
+    assert str(pplan.plan_for("train")) == str(xla_plan.plan_for("train"))
+    attn = [str(seg.plan[0]["attn"]).split("+")[0]
+            for seg in pplan.plan_for("train").segments]
+    assert attn == ["LayerConfig{batch:(data), heads:(model)}",
+                    "LayerConfig{batch:(data,model)}"], attn
+    assert dispatch.spmd_maps() == {("flash_attention", "pallas"): 2}
+    assert dispatch.spmd_skips() == {}
+    hlo = compiled.as_text()
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq",
+                   "flash_attention_bwd_dkv"):
+        assert kernel in hlo, kernel
+    assert _held(compiled) < 15.75 * 2**30, _held(compiled)
+
+    def gathers(text):
+        return len(re.findall(r"= \S+ all-gather(?:-start)?\(", text))
+    assert gathers(hlo) <= gathers(xla_step.as_text()), (
+        gathers(hlo), gathers(xla_step.as_text()))
