@@ -18,7 +18,6 @@ __all__ = [
     "tpu_compiler_params",
     "make_mesh",
     "default_platform",
-    "is_tracer",
     "shard_map",
 ]
 
@@ -51,8 +50,3 @@ def shard_map(f, *, mesh, in_specs, out_specs):
 def default_platform() -> str:
     """'cpu' | 'gpu' | 'tpu' for the default JAX backend."""
     return jax.default_backend()
-
-
-def is_tracer(x: Any) -> bool:
-    """True when ``x`` is an abstract tracer (inside jit/grad tracing)."""
-    return isinstance(x, jax.core.Tracer)

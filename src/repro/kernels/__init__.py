@@ -1,7 +1,7 @@
 """Compute hot-spot kernels behind a backend-portable dispatch registry.
 
 ``dispatch`` is the registry (selection by platform/dtype/shape, env and
-context overrides, Pallas block-size autotune cache); ``ops`` holds the
+context overrides, selection counts); ``ops`` holds the
 jit'd public entry points; ``ref`` the pure-jnp oracles; the remaining
 modules register the Pallas-TPU / Pallas-interpret / chunked-XLA
 implementations.  Model code calls ``dispatch.call``/``ops.*`` — never a

@@ -121,10 +121,12 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 _PREF_K = (1024, 512, 256, 128, 64, 32, 16, 8)
 
 
-def _block_cands(k, block_k):
+def _block(k, block_k) -> int:
+    """The caller's block, capped at the cache length, else the largest
+    preferred block dividing it."""
     T = k.shape[2]
-    return ([min(block_k, T)] if block_k
-            else dispatch.block_candidates(T, _PREF_K))
+    return (min(block_k, T) if block_k
+            else dispatch.block_candidates(T, _PREF_K)[0])
 
 
 def _supports(q, k, v, kv_len, *, block_k=None):
@@ -132,26 +134,19 @@ def _supports(q, k, v, kv_len, *, block_k=None):
     # ref/xla backends — this kernel is single-token-per-slot only
     if q.ndim != 4 or k.shape != v.shape or q.shape[1] != k.shape[1]:
         return False
-    return k.shape[2] % _block_cands(k, block_k)[0] == 0
+    return k.shape[2] % _block(k, block_k) == 0
 
 
 def _supports_native(q, k, v, kv_len, *, block_k=None):
     # Mosaic needs the (G, block_k) score tile lane axis 128-aligned;
     # unaligned cache lengths fall back to the ref backend.
     return (_supports(q, k, v, kv_len, block_k=block_k)
-            and _block_cands(k, block_k)[0] % 128 == 0)
+            and _block(k, block_k) % 128 == 0)
 
 
 def _via_pallas(q, k, v, kv_len, *, block_k=None, interpret=False):
-    bks = _block_cands(k, block_k)
-    bk, = dispatch.tuned_blocks(
-        "decode_attention",
-        (q.shape, k.shape, str(q.dtype), interpret, block_k),
-        [(b,) for b in bks[:4]],
-        bench=lambda b: decode_attention(q, k, v, kv_len, block_k=b,
-                                         interpret=interpret),
-        args=(q, k, v, kv_len))
-    return decode_attention(q, k, v, kv_len, block_k=bk, interpret=interpret)
+    return decode_attention(q, k, v, kv_len, block_k=_block(k, block_k),
+                            interpret=interpret)
 
 
 dispatch.register("decode_attention", "pallas", platforms=("tpu",),

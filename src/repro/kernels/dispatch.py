@@ -9,8 +9,8 @@ predicates and priorities, so:
 * a JAX rename breaks one adapter, not every consumer;
 * CPU-only hosts transparently get the reference/XLA path (Pallas TPU
   kernels cannot lower to the CPU backend);
-* TPU hosts get the tuned native kernel with block sizes from a small
-  autotune cache.
+* TPU hosts get the native kernel, whose block sizes each kernel module
+  takes from its own preference list (:func:`block_candidates`).
 
 Overrides, strongest first:
   1. ``backend=`` argument to :func:`call`;
@@ -63,16 +63,12 @@ from __future__ import annotations
 
 import contextlib
 import functools
-import json
 import logging
 import math
 import os
-import re
 import threading
-import time
 from collections import Counter
-from dataclasses import dataclass, field
-from pathlib import Path
+from dataclasses import dataclass
 from typing import Any, Callable
 
 import jax
@@ -83,9 +79,6 @@ from repro.core.sharding import current_mesh, entry_axes
 log = logging.getLogger(__name__)
 
 ENV_GLOBAL = "REPRO_KERNEL_BACKEND"
-ENV_AUTOTUNE = "REPRO_KERNEL_AUTOTUNE"
-ENV_CACHE_DIR = "REPRO_AUTOTUNE_CACHE_DIR"
-ENV_PERSIST = "REPRO_AUTOTUNE_PERSIST"
 
 
 @dataclass(frozen=True)
@@ -356,197 +349,6 @@ def reset_selection_counts() -> None:
         _SELECTIONS.clear()
         _SPMD_SKIPS.clear()
         _SPMD_MAPS.clear()
-
-
-# --------------------------------------------------------------------------- #
-# Block-size autotune cache (Pallas path)
-#
-# Two layers: the in-process dict (consulted first, keyed by the full tuning
-# key), and a JSON file per device kind under ``autotune_cache_dir()`` so a
-# serve restart on the same hardware skips re-tuning.  Disk entries are
-# validated against the caller's candidate list before use — a stale or
-# corrupt file degrades to a fresh tune, never to a wrong block size.
-# --------------------------------------------------------------------------- #
-_TUNE_CACHE: dict[tuple, tuple] = {}
-_TUNE_LOCK = threading.Lock()          # guards the dicts/counters (fast ops)
-_DISK_LOCK = threading.Lock()          # serializes file I/O, outside _TUNE_LOCK
-_TUNE_STATS: Counter = Counter()
-_DISK_CACHE: dict[str, tuple] = {}     # str(key) -> choice, mirror of the file
-_DISK_LOADED: set[str] = set()         # cache-file paths already merged
-
-
-def autotune_enabled() -> bool:
-    return os.environ.get(ENV_AUTOTUNE, "1") not in ("0", "false", "off")
-
-
-def persist_enabled() -> bool:
-    return os.environ.get(ENV_PERSIST, "1") not in ("0", "false", "off")
-
-
-def autotune_cache_dir() -> Path:
-    d = os.environ.get(ENV_CACHE_DIR)
-    return Path(d) if d else Path.home() / ".cache" / "repro" / "autotune"
-
-
-def _device_kind() -> str:
-    try:
-        kind = jax.devices()[0].device_kind
-    except Exception:  # pre-init / exotic backends: fall back to platform
-        kind = compat.default_platform()
-    return re.sub(r"[^A-Za-z0-9._-]+", "_", kind.strip()) or "unknown"
-
-
-def autotune_cache_path() -> Path:
-    return autotune_cache_dir() / f"{_device_kind()}.json"
-
-
-def autotune_cache_stats() -> dict[str, int]:
-    """Counters: ``memory_hits`` / ``disk_hits`` (cache served), ``tuned``
-    (a choice was computed fresh — heuristic or timed), ``disk_writes``,
-    ``disk_errors`` (unreadable/corrupt cache files, recovered by
-    re-tuning), ``compile_failures`` (timed candidates that failed to
-    compile or run, each logged at warning)."""
-    with _TUNE_LOCK:
-        return dict(_TUNE_STATS)
-
-
-def clear_autotune_cache(*, reset_stats: bool = True) -> None:
-    """Drop the in-process cache (and forget which disk files were merged,
-    so a changed ``REPRO_AUTOTUNE_CACHE_DIR`` is re-read).  The on-disk
-    files themselves are left alone."""
-    with _TUNE_LOCK:
-        _TUNE_CACHE.clear()
-        _DISK_CACHE.clear()
-        _DISK_LOADED.clear()
-        if reset_stats:
-            _TUNE_STATS.clear()
-
-
-def _merge_disk_cache_locked(path: Path) -> None:
-    """Merge ``path`` into the in-memory mirror once (under _TUNE_LOCK)."""
-    key = str(path)
-    if key in _DISK_LOADED:
-        return
-    _DISK_LOADED.add(key)
-    if not path.exists():
-        return
-    try:
-        raw = json.loads(path.read_text())
-        if not isinstance(raw, dict):
-            raise ValueError(f"expected a JSON object, got {type(raw)}")
-        for ks, v in raw.items():
-            _DISK_CACHE[ks] = tuple(int(b) for b in v)
-    except Exception:
-        _TUNE_STATS["disk_errors"] += 1
-        log.warning("unreadable autotune cache %s; re-tuning", path,
-                    exc_info=True)
-
-
-def _write_disk_cache(path: Path) -> None:
-    """Atomically rewrite ``path`` from the in-memory mirror (tmp file +
-    ``os.replace`` so concurrent readers never see a torn file).  The
-    current file contents are re-read and merged first — entries tuned by
-    a concurrent process since our initial merge survive (ours win on
-    conflict); a corrupt file is simply overwritten.  File I/O runs under
-    _DISK_LOCK only, so memory-hit lookups never block behind the disk;
-    _TUNE_LOCK is taken briefly (and never the other way around) to
-    touch the mirror and counters."""
-    with _DISK_LOCK:
-        try:
-            path.parent.mkdir(parents=True, exist_ok=True)
-            on_disk: dict[str, tuple] = {}
-            if path.exists():
-                try:
-                    raw = json.loads(path.read_text())
-                    if isinstance(raw, dict):
-                        on_disk = {ks: tuple(int(b) for b in v)
-                                   for ks, v in raw.items()}
-                except Exception:
-                    pass  # corrupt: the rewrite below repairs it
-            with _TUNE_LOCK:
-                for ks, v in on_disk.items():
-                    _DISK_CACHE.setdefault(ks, v)
-                snap = dict(_DISK_CACHE)
-            tmp = path.with_name(f"{path.name}.tmp{os.getpid()}")
-            tmp.write_text(json.dumps(
-                {k: list(v) for k, v in sorted(snap.items())}, indent=1))
-            os.replace(tmp, path)
-            with _TUNE_LOCK:
-                _TUNE_STATS["disk_writes"] += 1
-        except OSError:
-            with _TUNE_LOCK:
-                _TUNE_STATS["disk_errors"] += 1
-            log.warning("cannot persist autotune cache to %s", path,
-                        exc_info=True)
-
-
-def _is_concrete(args) -> bool:
-    return not any(compat.is_tracer(a) for a in jax.tree.leaves(args))
-
-
-def _time_once(fn, *args) -> float:
-    t0 = time.perf_counter()
-    jax.block_until_ready(fn(*args))
-    return time.perf_counter() - t0
-
-
-def tuned_blocks(op: str, key: tuple, candidates: list[tuple],
-                 bench: Callable[..., Any], args: tuple) -> tuple:
-    """Pick block sizes for a Pallas kernel invocation.
-
-    ``candidates`` are already filtered for validity (divisibility); the
-    first entry is the heuristic default.  On a TPU host with concrete
-    (non-traced) inputs and autotuning enabled, each candidate is timed
-    (compile excluded via a warm-up run) and the winner cached under
-    ``(op, key)``.  Under tracing the heuristic is returned WITHOUT
-    caching — so dispatch stays usable inside ``jit`` and a later eager
-    warm-up with real arrays can still tune the same shape (tuned
-    entries then serve subsequent traces).
-    """
-    if not candidates:
-        raise ValueError(f"no valid block-size candidates for {op} {key}")
-    cache_key = (op,) + key
-    persist = persist_enabled()
-    with _TUNE_LOCK:
-        if cache_key in _TUNE_CACHE:
-            _TUNE_STATS["memory_hits"] += 1
-            return _TUNE_CACHE[cache_key]
-        if persist:
-            _merge_disk_cache_locked(autotune_cache_path())
-            disk = _DISK_CACHE.get(repr(cache_key))
-            if disk is not None and disk in candidates:
-                _TUNE_STATS["disk_hits"] += 1
-                _TUNE_CACHE[cache_key] = disk
-                return disk
-    choice = candidates[0]
-    if len(candidates) == 1:
-        pass                          # nothing to tune; cache the choice
-    elif not (autotune_enabled() and compat.default_platform() == "tpu"):
-        pass                          # tuning can never run: cache heuristic
-    elif not _is_concrete(args):
-        return choice                 # tracing: usable now, tunable later
-    else:
-        timings = []
-        for cand in candidates:
-            try:
-                _time_once(bench, *cand)          # compile + warm up
-                timings.append((_time_once(bench, *cand), cand))
-            except Exception:                     # candidate may not compile
-                with _TUNE_LOCK:
-                    _TUNE_STATS["compile_failures"] += 1
-                log.warning("autotune candidate %s failed for %s %s",
-                            cand, op, key, exc_info=True)
-        if timings:
-            choice = min(timings)[1]
-            log.info("autotuned %s %s -> %s", op, key, choice)
-    with _TUNE_LOCK:
-        _TUNE_CACHE[cache_key] = choice
-        _TUNE_STATS["tuned"] += 1
-        if persist:
-            _DISK_CACHE[repr(cache_key)] = choice
-    if persist:
-        _write_disk_cache(autotune_cache_path())
-    return choice
 
 
 def block_candidates(dim: int, preferred: tuple[int, ...]) -> list[int]:

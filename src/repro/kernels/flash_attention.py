@@ -355,13 +355,15 @@ _PREF_Q = (512, 256, 128, 64, 32, 16, 8)
 _PREF_K = (512, 256, 128, 64, 32, 16, 8)
 
 
-def _block_cands(q, k, block_q, block_k):
+def _blocks(q, k, block_q, block_k) -> tuple[int, int]:
+    """(block_q, block_k): the caller's, capped at the lengths, else the
+    largest preferred block dividing each length."""
     S, T = q.shape[2], k.shape[2]
-    bqs = ([min(block_q, S)] if block_q
-           else dispatch.block_candidates(S, _PREF_Q))
-    bks = ([min(block_k, T)] if block_k
-           else dispatch.block_candidates(T, _PREF_K))
-    return bqs, bks
+    bq = (min(block_q, S) if block_q
+          else dispatch.block_candidates(S, _PREF_Q)[0])
+    bk = (min(block_k, T) if block_k
+          else dispatch.block_candidates(T, _PREF_K)[0])
+    return bq, bk
 
 
 def _supports(q, k, v, *, causal=True, block_q=None, block_k=None):
@@ -369,8 +371,8 @@ def _supports(q, k, v, *, causal=True, block_q=None, block_k=None):
     _, KH, T, _ = k.shape
     if H % KH != 0 or k.shape != v.shape:
         return False
-    bqs, bks = _block_cands(q, k, block_q, block_k)
-    return S % bqs[0] == 0 and T % bks[0] == 0
+    bq, bk = _blocks(q, k, block_q, block_k)
+    return S % bq == 0 and T % bk == 0
 
 
 def _supports_native(q, k, v, *, causal=True, block_q=None, block_k=None):
@@ -381,8 +383,8 @@ def _supports_native(q, k, v, *, causal=True, block_q=None, block_k=None):
     if not _supports(q, k, v, causal=causal, block_q=block_q,
                      block_k=block_k):
         return False
-    bqs, bks = _block_cands(q, k, block_q, block_k)
-    return bqs[0] % 8 == 0 and bks[0] % 128 == 0
+    bq, bk = _blocks(q, k, block_q, block_k)
+    return bq % 8 == 0 and bk % 128 == 0
 
 
 # The backward puts both block sizes on the lane axis (query blocks in
@@ -397,30 +399,25 @@ _PREF_BWD = (1024, 512, 256, 128)
 _BWD_TILE_ELEMS = 1024 * 128
 
 
-def _bwd_block_cands(q, k) -> list[tuple[int, int]]:
-    """(block_q, block_k) pairs the backward can take, heuristic first;
-    empty where the sequence lengths admit no lane-aligned block."""
+def _bwd_blocks(q, k) -> tuple[int, int] | None:
+    """The backward's (block_q, block_k): the largest preferred
+    lane-aligned block dividing each sequence length; None where a
+    length admits none."""
     pref = tuple(b for b in _PREF_BWD if b * q.shape[3] <= _BWD_TILE_ELEMS)
     bqs, bks = ([b for b in dispatch.block_candidates(n, pref)
                  if b % 128 == 0] for n in (q.shape[2], k.shape[2]))
-    return [(bq, bk) for bq in bqs for bk in bks]
+    return (bqs[0], bks[0]) if bqs and bks else None
 
 
 def _backward(q, k, v, o, do, *, causal, interpret):
-    cands = _bwd_block_cands(q, k)
-    if not cands:
+    blocks = _bwd_blocks(q, k)
+    if blocks is None:
         dispatch.record_selection("flash_attention_bwd", "reference")
         _, vjp = jax.vjp(functools.partial(mha_xla.flash_attention_xla,
                                            causal=causal), q, k, v)
         return vjp(do)
     dispatch.record_selection("flash_attention_bwd", "pallas")
-    bq, bk = dispatch.tuned_blocks(
-        "flash_attention_bwd",
-        (q.shape, k.shape, str(q.dtype), causal, interpret), cands,
-        bench=lambda bq_, bk_: flash_attention_bwd(
-            q, k, v, o, do, causal=causal, block_q=bq_, block_k=bk_,
-            interpret=interpret),
-        args=(q, k, v, o, do))
+    bq, bk = blocks
     return flash_attention_bwd(q, k, v, o, do, causal=causal, block_q=bq,
                                block_k=bk, interpret=interpret)
 
@@ -449,16 +446,7 @@ def _grad_ready(causal, block_q, block_k, interpret):
 
 def _via_pallas(q, k, v, *, causal=True, block_q=None, block_k=None,
                 interpret=False):
-    bqs, bks = _block_cands(q, k, block_q, block_k)
-    cands = [(bq, bk) for bq in bqs[:3] for bk in bks[:3]]
-    bq, bk = dispatch.tuned_blocks(
-        "flash_attention",
-        (q.shape, k.shape, str(q.dtype), causal, interpret,
-         block_q, block_k), cands,
-        bench=lambda bq_, bk_: flash_attention(
-            q, k, v, causal=causal, block_q=bq_, block_k=bk_,
-            interpret=interpret),
-        args=(q, k, v))
+    bq, bk = _blocks(q, k, block_q, block_k)
     return _grad_ready(causal, bq, bk, interpret)(q, k, v)
 
 

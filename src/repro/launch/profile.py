@@ -6,9 +6,8 @@ Measures the chip roofline (dense-matmul FLOP/s, HBM stream bandwidth),
 every eligible kernel dispatch backend per (op, shape class), and — when
 more than one device is visible — the four ring collectives over a
 message-size ladder on each mesh axis, fitted to alpha-beta curves.  The
-resulting JSON feeds ``--device-profile`` on train / serve / dryrun and
-``benchmarks/serving_throughput.py``, calibrating the plan search's cost
-model to the measured machine.
+resulting JSON feeds ``--device-profile`` on train / serve / dryrun,
+calibrating the plan search's cost model to the measured machine.
 
 On a CPU host, 8 virtual devices for the collective sweep come from::
 

@@ -40,7 +40,6 @@ non-slot-shaped memory leaf), with the same warm-up discipline.
 from __future__ import annotations
 
 import argparse
-import os
 import time
 
 import jax
@@ -50,7 +49,6 @@ import numpy as np
 from repro import configs
 from repro.core.sharding import use_mesh
 from repro.data import make_dataset
-from repro.kernels import dispatch as kernel_dispatch
 from repro.models import model_module
 from repro.models.arch import ShapeSpec
 from repro.plans import ParallelPlan, STRATEGIES, resolve_plan
@@ -277,11 +275,6 @@ def build_parser() -> argparse.ArgumentParser:
                     help="measured DeviceProfile JSON (launch.profile); "
                          "calibrates the plan search's cost model to this "
                          "host instead of the analytic constants")
-    ap.add_argument("--autotune-cache-dir", default="",
-                    help="directory for the persistent Pallas block-size "
-                         "autotune cache (default ~/.cache/repro/autotune; "
-                         "same as REPRO_AUTOTUNE_CACHE_DIR) — a restart on "
-                         "the same device kind skips re-tuning")
     return ap
 
 
@@ -293,8 +286,6 @@ def run(argv=None) -> dict:
     model).  The encoder-decoder arch returns ``completions=None``."""
     args = build_parser().parse_args(argv)
     enable_compile_cache()
-    if args.autotune_cache_dir:
-        os.environ[kernel_dispatch.ENV_CACHE_DIR] = args.autotune_cache_dir
 
     arch = reduced_arch(configs.get(args.arch), args.width, args.depth,
                         args.vocab, args.experts)

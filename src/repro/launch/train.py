@@ -19,7 +19,6 @@ import argparse
 import dataclasses
 import json
 import math
-import os
 import time
 from pathlib import Path
 
@@ -106,10 +105,6 @@ def build_parser() -> argparse.ArgumentParser:
                          "(pallas|interpret|xla|ref) for every op — "
                          "attention, wkv6, mamba_scan, moe_dispatch_combine;"
                          " default auto")
-    ap.add_argument("--autotune-cache-dir", default="",
-                    help="directory for the persistent Pallas block-size "
-                         "autotune cache (default ~/.cache/repro/autotune; "
-                         "same as REPRO_AUTOTUNE_CACHE_DIR)")
     ap.add_argument("--device-profile", default="",
                     help="measured DeviceProfile JSON (launch.profile); "
                          "calibrates the plan search's cost model to this "
@@ -169,8 +164,6 @@ def run(argv=None) -> dict:
     final parameters."""
     args = build_parser().parse_args(argv)
     enable_compile_cache()
-    if args.autotune_cache_dir:
-        os.environ[kernel_dispatch.ENV_CACHE_DIR] = args.autotune_cache_dir
 
     arch = reduced_arch(configs.get(args.arch), args.width, args.depth,
                         args.vocab, args.experts)

@@ -9,8 +9,7 @@ prefill is a batch-1 long sequence and training a large dense batch.  A
 per phase (``train`` / ``prefill`` / ``decode``) together with the mesh
 it was searched for and provenance metadata, and round-trips through a
 versioned JSON schema so a plan can outlive the process that searched it
-(``plan.save(path)`` / ``ParallelPlan.load(path, arch=arch)``) — the
-strategy analogue of the persisted autotune cache.
+(``plan.save(path)`` / ``ParallelPlan.load(path, arch=arch)``).
 
 Loading refuses loudly on a corrupt file, a schema-version mismatch, or
 an architecture mismatch (a plan realized against the wrong arch would
@@ -287,7 +286,7 @@ class ParallelPlan:
         }
 
     def save(self, path) -> Path:
-        """Atomic write (tmp + rename), like the autotune cache."""
+        """Atomic write (tmp + rename)."""
         path = Path(path)
         path.parent.mkdir(parents=True, exist_ok=True)
         payload = json.dumps(self.to_json(), indent=1)

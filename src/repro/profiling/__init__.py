@@ -7,7 +7,7 @@ layer for our stack.  :mod:`~repro.profiling.microbench` times real jitted
 executions (chip roofline, kernel backends through the dispatcher,
 collectives over the device mesh); :mod:`~repro.profiling.profile`
 persists them as a versioned :class:`DeviceProfile` JSON artifact (the
-third on-disk artifact next to ParallelPlan JSON and the autotune cache);
+on-disk artifact next to ParallelPlan JSON);
 :meth:`repro.core.cost_model.CostModel.from_profile` consumes one, field
 by field, falling back to the analytic constants for anything the profile
 lacks.  :mod:`~repro.profiling.calibration` closes the loop with a

@@ -11,8 +11,8 @@ model.  For every layer node it compares
   FLOPs and an elementwise stream sized to its HBM bytes, combined as
   ``max`` exactly like the roofline.
 
-The relative error per layer, and its median (``cost_model_rel_error``,
-the number the CI bench gates), quantify how far the cost model's
+The relative error per layer, and its median (``cost_model_rel_error``),
+quantify how far the cost model's
 absolute scale is from this machine.  An analytic (uncalibrated) model on
 CPU is off by orders of magnitude; a profiled one should land within a
 small factor.

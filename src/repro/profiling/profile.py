@@ -14,11 +14,11 @@ in :mod:`repro.core.device` field-by-field:
   fastest backend for that op (the measured replacement for the analytic
   kernel cost hooks in :mod:`repro.core.cost_model`).
 
-Persistence mirrors the other two on-disk artifacts (ParallelPlan JSON and
-the autotune cache): a schema tag + version with explicit refusal on
-mismatch or corruption, atomic tmp-file + ``os.replace`` writes, a default
-location keyed by device kind, and provenance metadata recording what
-measured the numbers.
+Persistence mirrors the other on-disk artifact (ParallelPlan JSON): a
+schema tag + version with explicit refusal on mismatch or corruption,
+atomic tmp-file + ``os.replace`` writes, a default location keyed by
+device kind, and provenance metadata recording what measured the
+numbers.
 """
 
 from __future__ import annotations
@@ -270,7 +270,7 @@ def _median(xs) -> float:
 
 
 def profile_dir() -> Path:
-    """Default profile directory, next to the autotune cache; overridable
+    """Default profile directory, ``~/.cache/repro/profiles``; overridable
     via ``REPRO_PROFILE_DIR``."""
     d = os.environ.get(ENV_PROFILE_DIR)
     return Path(d) if d else Path.home() / ".cache" / "repro" / "profiles"

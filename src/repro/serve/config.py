@@ -1,12 +1,11 @@
 """`ServeConfig`: one consolidated, validated knob surface for the
 serve engine.
 
-The engine grew its knobs one PR at a time — slot pool, paging, chunked
+The engine grew its knobs one at a time — slot pool, paging, chunked
 prefill, and now prefix caching — and every layer above it (the launch
-driver, the serving benchmark, the tests) re-spelled the same widening
-bare-kwarg list.  ``ServeConfig`` freezes that surface into a single
-dataclass consumed by :class:`repro.serve.ServeEngine`,
-``repro.launch.serve`` and ``benchmarks.serving_throughput``; the old
+driver, the tests) re-spelled the same widening bare-kwarg list.
+``ServeConfig`` freezes that surface into a single dataclass consumed by
+:class:`repro.serve.ServeEngine` and ``repro.launch.serve``; the old
 bare kwargs keep working for one release via a mapping shim on the
 engine that emits ``DeprecationWarning``.
 """

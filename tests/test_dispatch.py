@@ -298,28 +298,11 @@ def test_flash_backward_path_is_counted(S, path):
 
 
 # --------------------------------------------------------------------------- #
-# autotune cache
+# block sizes
 # --------------------------------------------------------------------------- #
 def test_block_candidates():
     assert dispatch.block_candidates(256, (512, 256, 128)) == [256, 128]
     assert dispatch.block_candidates(100, (512, 256, 128)) == [100]
-
-
-def test_tuned_blocks_caches_heuristic():
-    dispatch.clear_autotune_cache()
-    calls = []
-
-    def bench(b):
-        calls.append(b)
-
-    got = dispatch.tuned_blocks("op_x", ("key",), [(128,), (64,)], bench,
-                                args=())
-    assert got == (128,)  # heuristic (first candidate) off-TPU
-    assert dispatch.tuned_blocks("op_x", ("key",), [(64,)], bench,
-                                 args=()) == (128,)  # cached
-    if compat.default_platform() != "tpu":
-        assert calls == []  # benchmarking never runs off-TPU
-    dispatch.clear_autotune_cache()
 
 
 # --------------------------------------------------------------------------- #

@@ -103,8 +103,14 @@ def test_paged_matches_dense_engine(name):
         assert got[2][1] == "eos"
     if paged.paged:
         assert paged.peak_blocks_in_use > 0
+        # the pool holds max_batch x pages usable blocks plus the trash
+        # block, against the dense layout's max_batch x max_len rows
+        pages = -(-max_len // BS)
+        assert (paged.kv_bytes_reserved * 2 * max_len
+                == dense.kv_bytes_reserved * (2 * pages + 1) * BS)
     else:
         assert name == "rwkv6_1b6"     # no KV leaves -> paging no-op
+        assert paged.kv_bytes_reserved == dense.kv_bytes_reserved == 0
 
 
 def test_block_free_list_restored_after_retires():
@@ -158,6 +164,8 @@ def test_submit_truncates_instead_of_rejecting_and_raises_pool_exhausted():
         assert c.finish_reason == "length"
         assert len(c.tokens) == max_len - len(p8) + 1
         outs[bs] = c.tokens
+        if not bs:
+            dense_bytes = engine.kv_bytes_reserved
         with pytest.raises(ValueError, match="exceeds the cache row"):
             engine.submit(Request(uid=1, prompt=(1,) * (max_len + 1),
                                   max_new_tokens=1))
@@ -168,6 +176,9 @@ def test_submit_truncates_instead_of_rejecting_and_raises_pool_exhausted():
         max_batch=1, max_len=max_len, kv_block_size=BS, kv_pool_blocks=1))
     with pytest.raises(PoolExhausted, match="KV blocks worst-case"):
         small.submit(Request(uid=2, prompt=p8, max_new_tokens=99))
+    # a bounded pool reserves its usable blocks plus the trash block, a
+    # share of the dense row's bytes that does not depend on the traffic
+    assert small.kv_bytes_reserved * max_len == dense_bytes * (1 + 1) * BS
 
 
 def test_scheduler_admits_on_blocks_not_slots():

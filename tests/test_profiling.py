@@ -1,9 +1,8 @@
 """Measured device profiles: persistence hygiene (round-trip, corrupt
-and version refusal — same contract as the ParallelPlan and autotune
-caches), the alpha-beta fit, field-by-field analytic fallback, the
-no-profile bit-identity guarantee over every arch, and (slow) the
-end-to-end demonstration that a measured profile can move the searched
-plan."""
+and version refusal — same contract as the ParallelPlan JSON), the
+alpha-beta fit, field-by-field analytic fallback, the no-profile
+bit-identity guarantee over every arch, and (slow) the end-to-end
+demonstration that a measured profile can move the searched plan."""
 
 import json
 import subprocess

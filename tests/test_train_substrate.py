@@ -194,3 +194,24 @@ def test_elastic_restore_dtype_and_shape(tmp_path):
     assert step == 1
     assert all(x.dtype == jnp.bfloat16
                for x in jax.tree.leaves(state["params"]))
+
+
+def test_compile_cache_dir_env_or_checkout(monkeypatch, tmp_path):
+    """The persistent compilation cache honours JAX_COMPILATION_CACHE_DIR
+    (JAX reads it; the helper sets nothing) and otherwise sits at a fixed
+    ``.jax_cache`` in the checkout."""
+    from pathlib import Path
+
+    from repro.launch import compile_cache
+
+    updates = []
+    monkeypatch.setattr(compile_cache.jax.config, "update",
+                        lambda *a: updates.append(a))
+    monkeypatch.setenv(compile_cache.ENV, str(tmp_path))
+    assert compile_cache.enable_compile_cache() == tmp_path
+    assert updates == []
+    monkeypatch.delenv(compile_cache.ENV)
+    got = compile_cache.enable_compile_cache()
+    root = Path(__file__).resolve().parents[1]
+    assert got == root / ".jax_cache"
+    assert updates == [("jax_compilation_cache_dir", str(got))]
